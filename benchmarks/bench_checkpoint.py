@@ -43,6 +43,7 @@ from repro.configs import get_smoke_config
 from repro.data.pipeline import DataPipeline, SyntheticLMSource
 from repro.dsm.api import CXL0Config, open_cxl0
 from repro.dsm.pool import DSMPool
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build
 from repro.train.loop import run_durable_loop
 from repro.train.state import init_train_state
@@ -127,7 +128,7 @@ def bench_mesh_commit(bench, tmp: str, *, n_leaves=8, dim=512,
         bench.record("ckpt_mesh_skipped", True,
                      f"needs 8 host devices, have {jax.device_count()}")
         return
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     sh = jax.sharding.NamedSharding(
         mesh, jax.sharding.PartitionSpec("data", "model"))
     key = jax.random.PRNGKey(0)

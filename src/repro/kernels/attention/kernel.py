@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 NEG_INF = -1e30
 
 
@@ -139,7 +137,7 @@ def flash_attention_kernel(
             pltpu.VMEM((block_q,), jnp.float32),      # running denom
             pltpu.VMEM((block_q, hd_v), jnp.float32),  # output acc
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _scan_kernel(dA_ref, dBu_ref, C_ref, h0_ref, y_ref, hout_ref, h_scr, *,
                  block_s: int, seq_s: int):
@@ -104,7 +102,7 @@ def selective_scan_kernel(dA, dBu, C, h0, *, block_s: int = 64,
             jax.ShapeDtypeStruct((B, I, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_i, N), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dA, dBu, C, h0)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_scr):
     di = pl.program_id(3)
@@ -73,7 +71,7 @@ def grouped_matmul_kernel(x, w, *, block_c: int = 128, block_f: int = 128,
                                lambda e, c, f, d: (e, c, f)),
         out_shape=jax.ShapeDtypeStruct((E, C_p, F_p), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -13,11 +13,7 @@ from repro.kernels.moe_gmm.ref import grouped_matmul_ref
 def _pick_backend(backend: Optional[str]) -> str:
     if backend is not None:
         return backend
-    try:
-        plat = jax.devices()[0].platform
-    except RuntimeError:          # pragma: no cover
-        plat = "cpu"
-    return "pallas" if plat == "tpu" else "ref"
+    return "pallas" if jax.devices()[0].platform == "tpu" else "ref"
 
 
 @partial(jax.jit, static_argnames=("block_c", "block_f", "block_d",

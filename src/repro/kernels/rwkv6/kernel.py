@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
                  y_ref, sout_ref, S_scr, *, block_t: int, seq_t: int):
@@ -125,7 +123,7 @@ def wkv6_kernel(r, k, v, logw, u, S0, *, block_t: int = 64,
             jax.ShapeDtypeStruct((B, H, n, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(rt, kt, vt, lwt, u, S0)

@@ -14,11 +14,7 @@ from repro.kernels.rwkv6.ref import wkv6_ref
 def _pick_backend(backend: Optional[str]) -> str:
     if backend is not None:
         return backend
-    try:
-        plat = jax.devices()[0].platform
-    except RuntimeError:          # pragma: no cover
-        plat = "cpu"
-    return "pallas" if plat == "tpu" else "ref"
+    return "pallas" if jax.devices()[0].platform == "tpu" else "ref"
 
 
 @partial(jax.jit, static_argnames=("block_t", "backend"))

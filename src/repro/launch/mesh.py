@@ -6,24 +6,36 @@ is (data=16, model=16) = 256 chips (one TPU v5e pod); the multi-pod mesh
 adds a leading pod axis: (pod=2, data=16, model=16) = 512 chips.  Data
 parallelism (and FSDP weight sharding) runs over ('pod', 'data'); tensor/
 expert/sequence parallelism over 'model'.
+
+``make_mesh`` is the one place a mesh is built from a shape: every axis is
+``AxisType.Auto``, the type ``with_sharding_constraint`` (used throughout
+the model) accepts.  ``jax.make_mesh`` alone defaults to ``Explicit`` axes.
 """
 from __future__ import annotations
 
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """A mesh of ``shape`` named ``axes`` over the process's devices,
+    every axis Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int = 1, model: int = 1):
     """Tiny mesh over however many local devices exist (tests/examples)."""
     data = max(n_devices // model, 1)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh(spec: str):
@@ -33,9 +45,9 @@ def parse_mesh(spec: str):
     the axes the same way."""
     dims = tuple(int(d) for d in spec.lower().split("x"))
     if len(dims) == 2:
-        return jax.make_mesh(dims, ("data", "model"))
+        return make_mesh(dims, ("data", "model"))
     if len(dims) == 3:
-        return jax.make_mesh(dims, ("pod", "data", "model"))
+        return make_mesh(dims, ("pod", "data", "model"))
     raise ValueError(f"mesh spec {spec!r}: want DxM or PxDxM")
 
 
@@ -66,4 +78,4 @@ def rank_submesh(rank: int, live, *, axes=("data", "model")):
     pos = order.index(rank)
     mine = devs[pos * per:(pos + 1) * per] or [devs[pos % len(devs)]]
     arr = np.array(mine).reshape(len(mine), 1)
-    return jax.sharding.Mesh(arr, axes)
+    return jax.sharding.Mesh(arr, axes)      # Mesh axes default to Auto

@@ -30,6 +30,8 @@ import jax
 from repro.dsm.api import CXL0Config
 from repro.dsm.emu import PRESETS
 from repro.dsm.flit_runtime import AUTO_MODE, COMMIT_MODES
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_debug_mesh
 from repro.parallel.sharding import ctx_for_mesh
 from repro.serve.engine import build_serve_engine, servable_archs
 from repro.serve.trace import synthetic_trace, trace_t_max
@@ -77,6 +79,7 @@ def main():
                     help="fleet: disable content-addressed cross-engine "
                          "prefix blocks")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.commit_mode == AUTO_MODE and args.topology is None:
         ap.error("--commit-mode auto requires --topology")
     if args.topology is not None and args.pool is None:
@@ -90,9 +93,7 @@ def main():
             ap.error("fleet serving is continuous-batching only")
         return _fleet_main(args)
 
-    n_dev = jax.device_count()
-    mesh = jax.make_mesh((max(n_dev // args.mesh_model, 1),
-                          args.mesh_model), ("data", "model"))
+    mesh = make_debug_mesh(jax.device_count(), model=args.mesh_model)
     ctx = ctx_for_mesh(mesh)
 
     new_tokens = tuple(int(t) for t in args.new_tokens.split(","))

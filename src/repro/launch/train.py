@@ -29,6 +29,8 @@ from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.data.pipeline import DataPipeline, SyntheticLMSource
 from repro.dsm.api import CXL0Config
 from repro.dsm.emu import PRESETS
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build
 from repro.parallel.sharding import ctx_for_mesh
 from repro.parallel.compression import make_int8_transform
@@ -73,13 +75,14 @@ def main():
     ap.add_argument("--distributed", action="store_true",
                     help="call jax.distributed.initialize() (multi-host)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.distributed:
         jax.distributed.initialize()
 
     n_dev = jax.device_count()
     data = args.mesh_data or max(n_dev // args.mesh_model, 1)
-    mesh = jax.make_mesh((data, args.mesh_model), ("data", "model"))
+    mesh = make_mesh((data, args.mesh_model), ("data", "model"))
     ctx = ctx_for_mesh(mesh)
     print(f"mesh: data={data} model={args.mesh_model} "
           f"({n_dev} devices, process {jax.process_index()})")

@@ -27,24 +27,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-    _SHMAP_NO_CHECK = {"check_vma": False}
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # pre-rename API: the replication check is check_rep, not check_vma
-    _SHMAP_NO_CHECK = {"check_rep": False}
-
 from jax.sharding import PartitionSpec as P
-
-
-def _axis_size(name):
-    """jax.lax.axis_size is a newer addition; psum of 1 over the axis is
-    the classic spelling (constant-folded, no collective)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
 
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamDesc
@@ -177,7 +160,7 @@ def _moe_local_psum(cfg, tp_axis, dp_axes, fsdp_axes, x_loc, router_w, ws):
     """Decode path: replicated routing, local experts only, psum combine."""
     m = cfg.moe
     B_l, S_l, D = x_loc.shape
-    tp = _axis_size(tp_axis)
+    tp = jax.lax.axis_size(tp_axis)
     e_loc = m.n_experts // tp
     my = jax.lax.axis_index(tp_axis)
     x_flat = x_loc.reshape(-1, D)
@@ -228,11 +211,11 @@ def moe_forward(cfg: ModelConfig, p, x: jax.Array, *, parallel=None,
               for k in ws}
     body = _moe_local_a2a if mode == "a2a" else _moe_local_psum
     x_spec = P(dp, tp, None) if mode == "a2a" else P(dp, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(body, cfg, tp, dp, fsdp),
         mesh=parallel.mesh,
         in_specs=(x_spec, P(None, None), w_spec),
         out_specs=(x_spec, P()),
-        **_SHMAP_NO_CHECK)
+        check_vma=False)
     y, aux = fn(x, p["router"], ws)
     return y + shared, aux

@@ -21,7 +21,9 @@ class AdamWState(NamedTuple):
 
 def adamw_init(params, moment_dtype: str = "float32") -> AdamWState:
     dt = jnp.dtype(moment_dtype)
-    zeros = lambda p: jnp.zeros(p.shape, dt)
+    # zeros_like keeps each param's sharding: the moments start spread
+    # over the mesh like the params, not gathered on one device
+    zeros = lambda p: jnp.zeros_like(p, dtype=dt)
     return AdamWState(step=jnp.zeros((), jnp.int32),
                       mu=jax.tree_util.tree_map(zeros, params),
                       nu=jax.tree_util.tree_map(zeros, params))

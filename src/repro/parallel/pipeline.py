@@ -18,15 +18,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-    _SHMAP_NO_CHECK = {"check_vma": False}
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # pre-rename API: the replication check is check_rep, not check_vma
-    _SHMAP_NO_CHECK = {"check_rep": False}
-
 from jax.sharding import PartitionSpec as P
 
 
@@ -80,11 +71,11 @@ def gpipe_forward(apply_fn: Callable, mesh, stage_axis: str = "stage",
         # broadcast — ppermute can't fan one source out to all
         return jax.lax.psum(out, stage_axis)
 
-    return _shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        **_SHMAP_NO_CHECK)
+        check_vma=False)
 
 
 def pipeline_bubble_fraction(n_stages: int, n_microbatches: int) -> float:

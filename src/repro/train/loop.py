@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.data.pipeline import DataPipeline, PipelineState
 from repro.dsm.api import CXL0Context, open_cxl0
@@ -69,13 +70,15 @@ def _state_objects(state: TrainState, pipe_state: PipelineState):
 
 def _restore_placement(objs, templates):
     """Put recovered (host-resident) leaves back onto the device layout the
-    templates carry: a template leaf that is a device-sharded jax array
-    donates its ``sharding``, so a mesh run resumes device-sharded and the
-    NEXT commit can run device-local again.  Host templates pass through —
-    the non-mesh loop is unchanged."""
+    templates carry: a template leaf placed on the mesh donates its
+    ``NamedSharding``, so a mesh run resumes device-sharded and the NEXT
+    commit can run device-local again.  Every other leaf (host templates,
+    and unplaced scalars such as the optimizer step) stays on the host:
+    pinning it to one device would clash with the mesh-placed arguments
+    of the next step."""
     def place(r, t):
         sh = getattr(t, "sharding", None)
-        if isinstance(t, jax.Array) and sh is not None:
+        if isinstance(t, jax.Array) and isinstance(sh, NamedSharding):
             return jax.device_put(r, sh)
         return r
     return {name: jax.tree_util.tree_map(place, objs[name], templates[name])

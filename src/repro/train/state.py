@@ -23,8 +23,7 @@ class TrainState(NamedTuple):
 def init_train_state(params, key, moment_dtype: str = "float32") -> TrainState:
     return TrainState(params=params,
                       opt=adamw_init(params, moment_dtype),
-                      rng=jax.random.key_data(key) if hasattr(
-                          jax.random, "key_data") else key)
+                      rng=jax.random.key_data(key))
 
 
 def abstract_train_state(params_abstract,

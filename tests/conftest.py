@@ -29,9 +29,10 @@ def pytest_configure(config):
 def pallas_interpret() -> bool:
     """Platform-detected Pallas execution mode for kernel tests: compiled
     on a real accelerator backend, ``interpret=True`` on CPU hosts (same
-    kernel body, run by the Pallas interpreter — numerics identical)."""
-    from repro.kernels.compat import default_interpret
-    return default_interpret()
+    kernel body, run by the Pallas interpreter — numerics identical).
+    Pallas TPU kernels compile only against a TPU backend."""
+    import jax
+    return jax.default_backend() == "cpu"
 
 
 @pytest.fixture(scope="session")

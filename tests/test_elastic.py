@@ -23,6 +23,7 @@ SCRIPT = textwrap.dedent("""
     from repro.data.pipeline import DataPipeline, SyntheticLMSource, shard_plan
     from repro.dsm.pool import DSMPool
     from repro.dsm.recovery import RecoveryManager
+    from repro.launch.mesh import make_mesh
     from repro.models.registry import build
     from repro.parallel.sharding import ctx_for_mesh
     from repro.train.elastic import remesh, shardings_for, shrink_plan
@@ -35,7 +36,7 @@ SCRIPT = textwrap.dedent("""
     key = jax.random.PRNGKey(0)
 
     # --- run on the 8-device mesh, committing durably -------------------
-    mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh8 = make_mesh((4, 2), ("data", "model"))
     ctx8 = ctx_for_mesh(mesh8)
     params = bundle.init_params(key)
     sh8 = shardings_for(ctx8, bundle.descs)
@@ -47,7 +48,7 @@ SCRIPT = textwrap.dedent("""
     r = run_durable_loop(step8, state, pipe, pool, n_steps=4, commit_every=2)
 
     # --- "cluster shrinks": rebuild on a 4-device mesh ------------------
-    mesh4 = jax.make_mesh((2, 2), ("data", "model"))
+    mesh4 = make_mesh((2, 2), ("data", "model"))
     templates = _state_objects(r.state, r.pipeline_state)
     objs, rec_step, src = RecoveryManager(pool).recover(templates)
     assert rec_step == 3, rec_step
@@ -84,6 +85,7 @@ GROW_SCRIPT = textwrap.dedent("""
     from repro.data.pipeline import DataPipeline, SyntheticLMSource
     from repro.dsm.pool import DSMPool
     from repro.dsm.recovery import RecoveryManager
+    from repro.launch.mesh import make_mesh
     from repro.models.registry import build
     from repro.parallel.sharding import ctx_for_mesh
     from repro.train.elastic import grow_plan, remesh, shardings_for
@@ -96,7 +98,7 @@ GROW_SCRIPT = textwrap.dedent("""
     key = jax.random.PRNGKey(0)
 
     # --- run on a 4-device mesh, committing durably ---------------------
-    mesh4 = jax.make_mesh((2, 2), ("data", "model"))
+    mesh4 = make_mesh((2, 2), ("data", "model"))
     ctx4 = ctx_for_mesh(mesh4)
     params = bundle.init_params(key)
     sh4 = shardings_for(ctx4, bundle.descs)
@@ -108,7 +110,7 @@ GROW_SCRIPT = textwrap.dedent("""
     r = run_durable_loop(step4, state, pipe, pool, n_steps=4, commit_every=2)
 
     # --- "cluster grows": rebuild on the full 8-device mesh -------------
-    mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh8 = make_mesh((4, 2), ("data", "model"))
     templates = _state_objects(r.state, r.pipeline_state)
     objs, rec_step, src = RecoveryManager(pool).recover(templates)
     assert rec_step == 3, rec_step

@@ -17,10 +17,11 @@ import jax.numpy as jnp
 import pytest
 
 from repro.dsm.api import CXL0Config
+from repro.launch.mesh import make_mesh
 
 
 def _mesh(shape=(2, 4)):
-    return jax.make_mesh(shape, ("data", "model")[:len(shape)])
+    return make_mesh(shape, ("data", "model")[:len(shape)])
 
 
 def _tree(n_leaves=6, dim=64, seed=0):

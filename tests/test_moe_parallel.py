@@ -12,6 +12,7 @@ SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax, jax.numpy as jnp
     from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.models import moe as moe_mod
     from repro.models.params import init_params
     from repro.parallel.sharding import ctx_for_mesh
@@ -20,15 +21,14 @@ SCRIPT = textwrap.dedent("""
     key = jax.random.PRNGKey(0)
     p = init_params(moe_mod.moe_descs(cfg), key, cfg.param_dtype)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ctx_for_mesh(mesh)
     B, S, D = 4, 8, cfg.d_model
     x = jax.random.normal(jax.random.fold_in(key, 1), (B, S, D),
                           jnp.bfloat16)
 
     y_dense, aux_dense = moe_mod.moe_forward(cfg, p, x, parallel=None)
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-            else mesh:
+    with mesh:
         y_a2a, aux_a2a = jax.jit(
             lambda p, x: moe_mod.moe_forward(cfg, p, x, parallel=ctx,
                                              mode="a2a"))(p, x)

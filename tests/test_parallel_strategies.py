@@ -21,6 +21,7 @@ SCRIPT = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.models.registry import build
     from repro.parallel.sharding import ctx_for_mesh
     from repro.train.elastic import shardings_for
@@ -33,7 +34,7 @@ SCRIPT = textwrap.dedent("""
 
     ref, _ = bundle.loss(params, batch)          # no mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     outs = {}
     for strategy in ("tp", "dp_only"):
         ctx = ctx_for_mesh(mesh, strategy=strategy)

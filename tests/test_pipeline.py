@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.parallel.pipeline import gpipe_forward, pipeline_bubble_fraction
 
 
@@ -13,7 +14,7 @@ from repro.parallel.pipeline import gpipe_forward, pipeline_bubble_fraction
                            "_device_count=8 in CI)")
 def test_gpipe_matches_sequential():
     P_ = min(4, jax.device_count())
-    mesh = jax.make_mesh((P_,), ("stage",))
+    mesh = make_mesh((P_,), ("stage",))
     M, mb, d = 6, 2, 8
     key = jax.random.PRNGKey(0)
     stage_w = jax.random.normal(key, (P_, d, d)) * 0.3
