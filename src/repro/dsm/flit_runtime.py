@@ -55,6 +55,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
+from repro import obs
 from repro.dsm import meshio
 from repro.dsm.tiers import TierManager
 
@@ -145,7 +146,6 @@ class DurableCommitter:
         #: that was actually flushed — a later commit's meta (e.g. a newer
         #: serving session table) must never pair with these objects.
         self._pending: Optional[Tuple[int, List[str], Optional[dict]]] = None
-        self.stats: list = []
 
     def _hook(self, point: str, step: int):
         if self.fault_hook is not None:
@@ -208,13 +208,14 @@ class DurableCommitter:
         # concurrent rank's flushed-but-not-yet-committed objects.  With a
         # delegated completeOp, retention is the cluster layer's job.
         if self.retention is not None and self.complete_fn is None:
-            self.tiers.pool.gc(keep=self.retention)
+            with obs.span("dsm.commit.gc") as sp:
+                walked, deleted = self.tiers.pool.gc(keep=self.retention)
+                sp.set(files=walked, deleted=deleted)
         st = CommitStats(step, seq, len(written),
                          sum(o.nbytes for o in written.values()),
                          time.perf_counter() - t0, label,
                          (self.n_shards or 1) if "sharded" in self.mode
                          else 1)
-        self.stats.append(st)
         self._hook("post_completeOp", step)
         return st
 
@@ -235,26 +236,27 @@ class DurableCommitter:
         PREVIOUS step whose flushes were just joined (None on the first
         call)."""
         t0 = time.perf_counter()
-        self._resolve_mode()
-        if self.mode == "async":
-            return self._commit_async(step, meta, t0)
-        if self.mode == "sharded-async":
-            return self._commit_sharded_async(step, meta, t0)
-        self._hook("pre_flush", step)
-        written: Dict[str, Any] = {}
-        first = True
-        for name in self.tiers.hbm:
-            if self.mode == "sharded":
-                written[name] = self.tiers.rflush_sharded(
-                    name, self._resolve_shards(),
-                    post_first_shard=self._mid_flush_probe(first, step),
-                    device_local=self._device_local)
-            else:
-                written[name] = self.tiers.rflush(name)
-                if first:
-                    self._hook("mid_flush", step)
-            first = False
-        return self._complete_op(step, written, meta, t0, self.mode)
+        mode = self._resolve_mode()
+        with obs.span("dsm.commit", step=step, mode=mode):
+            if mode == "async":
+                return self._commit_async(step, meta, t0)
+            if mode == "sharded-async":
+                return self._commit_sharded_async(step, meta, t0)
+            self._hook("pre_flush", step)
+            written: Dict[str, Any] = {}
+            first = True
+            for name in self.tiers.hbm:
+                if mode == "sharded":
+                    written[name] = self.tiers.rflush_sharded(
+                        name, self._resolve_shards(),
+                        post_first_shard=self._mid_flush_probe(first, step),
+                        device_local=self._device_local)
+                else:
+                    written[name] = self.tiers.rflush(name)
+                    if first:
+                        self._hook("mid_flush", step)
+                first = False
+            return self._complete_op(step, written, meta, t0, mode)
 
     def _mid_flush_probe(self, first: bool, step: int):
         """The mid-flush fault-injection callback — ONLY materialized when a
@@ -305,12 +307,13 @@ class DurableCommitter:
         #                             leave already-popped names re-joinable
         written: Dict[str, Any] = {}
         first_err: Optional[BaseException] = None
-        for n in names:
-            try:
-                written[n] = self.tiers.flush_wait(n)
-            except Exception as e:   # join the rest, then surface the first
-                if first_err is None:
-                    first_err = e
+        with obs.span("dsm.commit.join", joined=prev_step):
+            for n in names:
+                try:
+                    written[n] = self.tiers.flush_wait(n)
+                except Exception as e:   # join the rest, raise the first
+                    if first_err is None:
+                        first_err = e
         if first_err is not None:
             raise first_err          # step simply not durable; no manifest
         return self._complete_op(prev_step, written, meta, t0, label)

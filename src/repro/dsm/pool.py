@@ -65,6 +65,7 @@ import numpy as np
 
 import jax
 
+from repro import obs
 from repro.dsm import stream
 from repro.dsm.stream import SpillArena  # noqa: F401  (re-export)
 
@@ -210,14 +211,15 @@ class PendingWrite:
     def finish(self) -> PoolObject:
         """Make the write durable (fsync) and visible (atomic rename).
         MStore semantics: returns only once the object is on storage."""
-        f, self._file = self._file, None
-        try:
-            f.flush()
-            os.fsync(f.fileno())
-        finally:
-            f.close()
-        os.replace(self._tmp, self._dst)
-        self._pool._finalize_write(self.name, self.version, self._dst)
+        with obs.span("dsm.flush.fsync", name=self.name):
+            f, self._file = self._file, None
+            try:
+                f.flush()
+                os.fsync(f.fileno())
+            finally:
+                f.close()
+            os.replace(self._tmp, self._dst)
+            self._pool._finalize_write(self.name, self.version, self._dst)
         return PoolObject(self.name, self.version, self.crc, self.nbytes)
 
     def abort(self):
@@ -277,20 +279,22 @@ class DSMPool:
         durable write (serialize + write + incremental CRC, single pass,
         no fsync).  Durability and visibility happen in the returned
         handle's ``finish()``."""
-        arrays, _ = _flatten(tree)
-        base = self._obj_path(name, version)
-        tmp_fd, tmp_name = self._mkstemp(base)
-        f = os.fdopen(tmp_fd, "wb")
-        try:
-            crc, nbytes, _ = stream.write_frame(
-                f, arrays, arena or self._arena)
-        except BaseException:
-            f.close()
+        with obs.span("dsm.flush.write", name=name) as sp:
+            arrays, _ = _flatten(tree)
+            base = self._obj_path(name, version)
+            tmp_fd, tmp_name = self._mkstemp(base)
+            f = os.fdopen(tmp_fd, "wb")
             try:
-                os.unlink(tmp_name)
-            except OSError:
-                pass
-            raise
+                crc, nbytes, _ = stream.write_frame(
+                    f, arrays, arena or self._arena)
+            except BaseException:
+                f.close()
+                try:
+                    os.unlink(tmp_name)
+                except OSError:
+                    pass
+                raise
+            sp.set(bytes=nbytes)
         return PendingWrite(self, name, version, crc, nbytes, f,
                             tmp_name, base + stream.SUFFIX)
 
@@ -349,24 +353,33 @@ class DSMPool:
         overwrite a file an existing manifest still references.  Handles
         namespaced names (``w<i>/<name>``): the object dir and its shard
         sibling dirs live under the namespace directory."""
-        best = 0
+        return self.scan_versions(name)[0]
+
+    def scan_versions(self, name: str) -> Tuple[int, int]:
+        """``max_version`` and the number of directory entries its walk
+        listed to find it."""
+        best = listed = 0
         parent = os.path.dirname(os.path.join(self.obj_dir, name))
         base = os.path.basename(name)
         prefix = base + ".s"
         if not os.path.isdir(parent):
-            return 0
-        for d in os.listdir(parent):
+            return 0, 0
+        entries = os.listdir(parent)
+        listed += len(entries)
+        for d in entries:
             if d != base and not (d.startswith(prefix)
                                   and d[len(prefix):].isdigit()):
                 continue
             p = os.path.join(parent, d)
             if not os.path.isdir(p):
                 continue
-            for fn in os.listdir(p):
+            files = os.listdir(p)
+            listed += len(files)
+            for fn in files:
                 stem = fn.split(".")[0]
                 if stem.isdigit():
                     best = max(best, int(stem))
-        return best
+        return best, listed
 
     def read_object(self, name: str, version: int, treedef_like,
                     expected_crc: Optional[int] = None) -> Any:
@@ -457,39 +470,41 @@ class DSMPool:
         durable inode, atomically renamed into place — half the fsyncs of
         writing the document twice.  On filesystems without hardlinks the
         head falls back to a second write."""
-        seq, dst = self._reserve_manifest_seq()
-        self._manifest_seq = seq
-        doc = {
-            "seq": seq,
-            "step": step,
-            "objects": {name: manifest_entry(o)
-                        for name, o in objects.items()},
-            "meta": meta or {},
-        }
-        tmp = os.path.join(self.path, f".manifest.tmp.{seq}")
-        with open(tmp, "w") as f:
-            json.dump(doc, f)
-            f.flush()
-            os.fsync(f.fileno())
-        # link the head's temp name to the fsync'd inode BEFORE the rename
-        # consumes ``tmp`` — no second serialize, no second fsync
-        head = os.path.join(self.path, "manifest.json")
-        tmp2 = os.path.join(self.path, f".manifest.head.tmp.{seq}")
-        try:
-            os.link(tmp, tmp2)
-        except OSError:
-            tmp2 = None                 # no hardlinks here: write it twice
-        os.replace(tmp, dst)
-        # update the convenience head pointer last (also atomic; with
-        # concurrent committers last-writer-wins — readers that need the
-        # true newest manifest use manifests_desc())
-        if tmp2 is None:
-            tmp2 = os.path.join(self.path, f".manifest.head.tmp.{seq}")
-            with open(tmp2, "w") as f:
+        with obs.span("dsm.commit.manifest", objects=len(objects)) as sp:
+            seq, dst = self._reserve_manifest_seq()
+            self._manifest_seq = seq
+            doc = {
+                "seq": seq,
+                "step": step,
+                "objects": {name: manifest_entry(o)
+                            for name, o in objects.items()},
+                "meta": meta or {},
+            }
+            tmp = os.path.join(self.path, f".manifest.tmp.{seq}")
+            with open(tmp, "w") as f:
                 json.dump(doc, f)
                 f.flush()
+                sp.set(bytes=f.tell())
                 os.fsync(f.fileno())
-        os.replace(tmp2, head)
+            # link the head's temp name to the fsync'd inode BEFORE the rename
+            # consumes ``tmp`` — no second serialize, no second fsync
+            head = os.path.join(self.path, "manifest.json")
+            tmp2 = os.path.join(self.path, f".manifest.head.tmp.{seq}")
+            try:
+                os.link(tmp, tmp2)
+            except OSError:
+                tmp2 = None                 # no hardlinks here: write it twice
+            os.replace(tmp, dst)
+            # update the convenience head pointer last (also atomic; with
+            # concurrent committers last-writer-wins — readers that need the
+            # true newest manifest use manifests_desc())
+            if tmp2 is None:
+                tmp2 = os.path.join(self.path, f".manifest.head.tmp.{seq}")
+                with open(tmp2, "w") as f:
+                    json.dump(doc, f)
+                    f.flush()
+                    os.fsync(f.fileno())
+            os.replace(tmp2, head)
         return seq
 
     def read_entry(self, name: str, entry: dict, treedef_like) -> Any:
@@ -499,26 +514,27 @@ class DSMPool:
         mirroring the write pipelines — and any torn or corrupt shard
         raises CorruptObjectError for the WHOLE object (recovery then falls
         back to an older manifest)."""
-        if not entry.get("sharded"):
-            return self.read_object(name, entry["version"], treedef_like,
-                                    expected_crc=entry.get("crc"))
-        leaves: List[Any] = [None] * entry["n_leaves"]
-        shards = list(zip(entry["shards"], entry["assignment"]))
-        from concurrent.futures import ThreadPoolExecutor
-        with ThreadPoolExecutor(max_workers=len(shards)) as ex:
-            parts = list(ex.map(
-                lambda sa: self.read_object(sa[0]["name"], sa[0]["version"],
-                                            [0] * len(sa[1]),
-                                            expected_crc=sa[0].get("crc")),
-                shards))
-        for (sh, idxs), part in zip(shards, parts):
-            for i, a in zip(idxs, part):
-                leaves[i] = a
-        if any(l is None for l in leaves):
-            raise CorruptObjectError(
-                f"{name}@{entry['version']}: incomplete shard assignment")
-        _, treedef = jax.tree_util.tree_flatten(treedef_like)
-        return jax.tree_util.tree_unflatten(treedef, leaves)
+        with obs.span("dsm.recover.read", name=name):
+            if not entry.get("sharded"):
+                return self.read_object(name, entry["version"], treedef_like,
+                                        expected_crc=entry.get("crc"))
+            leaves: List[Any] = [None] * entry["n_leaves"]
+            shards = list(zip(entry["shards"], entry["assignment"]))
+            from concurrent.futures import ThreadPoolExecutor
+            with ThreadPoolExecutor(max_workers=len(shards)) as ex:
+                parts = list(ex.map(
+                    lambda sa: self.read_object(
+                        sa[0]["name"], sa[0]["version"], [0] * len(sa[1]),
+                        expected_crc=sa[0].get("crc")),
+                    shards))
+            for (sh, idxs), part in zip(shards, parts):
+                for i, a in zip(idxs, part):
+                    leaves[i] = a
+            if any(l is None for l in leaves):
+                raise CorruptObjectError(
+                    f"{name}@{entry['version']}: incomplete shard assignment")
+            _, treedef = jax.tree_util.tree_flatten(treedef_like)
+            return jax.tree_util.tree_unflatten(treedef, leaves)
 
     def manifests_desc(self) -> List[dict]:
         """All manifests, newest first — ordered by (step, seq), so logical
@@ -545,7 +561,7 @@ class DSMPool:
         ms = self.manifests_desc()
         return ms[0] if ms else None
 
-    def gc(self, keep: int = 3):
+    def gc(self, keep: int = 3) -> Tuple[int, int]:
         """Drop all but the newest ``keep`` manifests + unreferenced
         versions (the committer's retention policy calls this after every
         completeOp).  Handles sharded entries (every referenced shard stays
@@ -573,8 +589,11 @@ class DSMPool:
         genuinely dead one falls behind the watermark and is collected).
         Versions of an object no kept manifest mentions at all are
         retired (e.g. a finished serving session's ``kv/<rid>``) and are
-        deleted entirely, directory included."""
+        deleted entirely, directory included.
+
+        Returns the number of object files walked and of those deleted."""
         keep = max(1, keep)
+        walked = deleted = 0
         ms = self.manifests_desc()
         keep_ms, drop_ms = ms[:keep], ms[keep:]
         live = set()
@@ -618,6 +637,7 @@ class DSMPool:
         for dirpath, dirnames, filenames in os.walk(self.obj_dir,
                                                     topdown=False):
             name = os.path.relpath(dirpath, self.obj_dir).replace(os.sep, "/")
+            walked += len(filenames)
             for fn in filenames:
                 stem = fn.split(".")[0]
                 if not stem.isdigit():
@@ -632,6 +652,7 @@ class DSMPool:
                     #             in-flight commit
                 try:
                     os.unlink(os.path.join(dirpath, fn))
+                    deleted += 1
                 except OSError:
                     pass
             if dirpath != self.obj_dir:
@@ -639,3 +660,4 @@ class DSMPool:
                     os.rmdir(dirpath)       # fails (harmlessly) if non-empty
                 except OSError:
                     pass
+        return walked, deleted

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from repro import obs
 from repro.dsm.pool import CorruptObjectError, DSMPool
 
 
@@ -113,7 +114,8 @@ class RecoveryManager:
         version (else it could mix steps — not linearizable).
         ``exact=False`` allows subset recovery from the pool (see
         ``recover_from_pool``)."""
-        pool_state = self.recover_from_pool(templates, exact=exact)
+        with obs.span("dsm.recover", objects=len(templates)):
+            pool_state = self.recover_from_pool(templates, exact=exact)
         best_peer: Optional[Dict[str, Any]] = None
         best_ver = -1
         for peer in peers:

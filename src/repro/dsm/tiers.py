@@ -37,6 +37,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import obs
 from repro.dsm import meshio, stream
 from repro.dsm.pool import (DSMPool, PoolObject, ShardedObject,
                             partition_leaves)
@@ -106,6 +107,16 @@ class TierManager:
                 out.append(a)
         return jax.tree_util.tree_unflatten(treedef, out)
 
+    def _snapshot(self, name: str):
+        """The host copy of ``name`` that a flush writes: the commit's
+        device-to-host gather."""
+        with obs.span("dsm.commit.snapshot", name=name) as sp:
+            tree = self._to_host_counted(self.hbm[name])
+            if sp:
+                sp.set(bytes=sum(l.nbytes for l in
+                                 jax.tree_util.tree_leaves(tree)))
+        return tree
+
     def _get_executor(self, n_workers: int) -> ThreadPoolExecutor:
         """One lazily-created pool of flush pipelines, sized by the first
         sharded flush (the shard count is constant for a run)."""
@@ -152,7 +163,10 @@ class TierManager:
         retained manifest still references."""
         self.hbm[name] = tree
         if name not in self.versions:
-            self.versions[name] = self.pool.max_version(name)
+            with obs.span("dsm.lstore.seed", name=name) as sp:
+                best, listed = self.pool.scan_versions(name)
+                sp.set(entries=listed)
+            self.versions[name] = best
         self.versions[name] += 1
 
     def rstore(self, name: str, peer: Any,
@@ -200,8 +214,7 @@ class TierManager:
         self.flit_counter[name] = self.flit_counter.get(name, 0) + 1
         try:
             obj = self.pool.write_object(
-                name, self.versions.get(name, 0),
-                self._to_host_counted(self.hbm[name]))
+                name, self.versions.get(name, 0), self._snapshot(name))
         finally:
             self.flit_counter[name] -= 1
         return obj
@@ -250,7 +263,7 @@ class TierManager:
             shards = [_shard_thunk(tuple(idxs)) for idxs in assignment]
         else:
             leaves = [np.asarray(l) for l in jax.tree_util.tree_leaves(
-                self._to_host_counted(self.hbm[name]))]
+                self._snapshot(name))]
             assignment = partition_leaves(
                 [a.nbytes for a in leaves], n_shards)
             n_leaves = len(leaves)
@@ -264,7 +277,7 @@ class TierManager:
                     futs.append(self._submit_split_phase(
                         ex, f"{name}.s{k}", version, shard))
                 else:
-                    futs.append(ex.submit(self._write_shard,
+                    futs.append(ex.submit(obs.carry(self._write_shard),
                                           f"{name}.s{k}", version, shard))
                 if k == 0 and post_first_shard is not None:
                     futs[0].result()
@@ -320,12 +333,12 @@ class TierManager:
                         pass
                     out.set_exception(e)
             try:
-                self._get_fsync_lane().submit(finish)
+                self._get_fsync_lane().submit(obs.carry(finish))
             except BaseException as e:     # lane torn down mid-shutdown
                 pending.abort()
                 out.set_exception(e)
 
-        ex.submit(serialize)
+        ex.submit(obs.carry(serialize))
         return out
 
     def _shard_join(self, name: str, version: int, n_leaves: int,
@@ -384,7 +397,7 @@ class TierManager:
         concurrent joiner knows the pool copy may be stale."""
         self.flit_counter[name] = self.flit_counter.get(name, 0) + 1
         version = self.versions.get(name, 0)
-        host_copy = self._to_host_counted(self.hbm[name])  # snapshot NOW
+        host_copy = self._snapshot(name)                   # snapshot NOW
 
         def work():
             # a failed write must surface at the join (flush_wait) AND the
@@ -403,7 +416,7 @@ class TierManager:
                 with self._lock:
                     self.flit_counter[name] -= 1
 
-        t = threading.Thread(target=work, daemon=True)
+        t = threading.Thread(target=obs.carry(work), daemon=True)
         self._flush_threads[name] = t
         t.start()
 
@@ -433,18 +446,22 @@ class TierManager:
         recovery: a stale write must fully land (or fail) BEFORE the next
         incarnation reuses version numbers, else an old flush could
         overwrite a new one's file after its manifest committed."""
-        for name, (_, _, _, futs) in list(self._sharded_futures.items()):
-            for f in futs:
-                try:
-                    f.result()
-                except Exception:
-                    pass
-            self.flit_counter[name] -= 1
-        self._sharded_futures.clear()
-        for name, t in list(self._flush_threads.items()):
-            t.join()            # work()'s finally lowered the counter,
-        #                         whether the write landed or failed
-        self._flush_threads.clear()
+        outstanding = (sum(len(p[3]) for p in self._sharded_futures.values())
+                       + len(self._flush_threads))
+        with obs.span("dsm.crash.join", writes=outstanding):
+            for name, (_, _, _, futs) in list(
+                    self._sharded_futures.items()):
+                for f in futs:
+                    try:
+                        f.result()
+                    except Exception:
+                        pass
+                self.flit_counter[name] -= 1
+            self._sharded_futures.clear()
+            for name, t in list(self._flush_threads.items()):
+                t.join()        # work()'s finally lowered the counter,
+            #                     whether the write landed or failed
+            self._flush_threads.clear()
         with self._lock:
             self._flush_results.clear()
             self._flush_errors.clear()

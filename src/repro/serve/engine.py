@@ -44,12 +44,14 @@ removes.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.serve.kvcache import TieredKVCache
 from repro.serve.paging import (BLOCK_TOKENS, BlockAllocator, BlockPager,
                                 BlockRef, BlockTable, STATE_BLOCK)
@@ -121,6 +123,8 @@ class ServeEngine:
         self.sched = SlotScheduler(n_slots)
         self.sessions: Dict[str, Session] = {}
         self.results: Dict[str, List[int]] = {}
+        #: rid -> perf_counter time of its ``submit``, until admitted
+        self._submitted: Dict[str, float] = {}
         self._resume_cache: Dict[str, Any] = {}
         #: recovered handoff tables of sessions we migrated OUT whose
         #: target never committed its adoption — the fleet resume
@@ -147,6 +151,7 @@ class ServeEngine:
     # -- request intake ------------------------------------------------------
     def submit(self, requests: Sequence[Request]):
         fresh = []
+        now = time.perf_counter()
         for r in requests:
             assert len(r.prompt) + r.max_new_tokens <= self.t_max, \
                 (r.rid, len(r.prompt), r.max_new_tokens, self.t_max)
@@ -154,6 +159,7 @@ class ServeEngine:
                 continue    # recovered, resuming, migrated, or retired —
                 #             this engine already accounts for the rid
             fresh.append(r)
+            self._submitted[r.rid] = now
         self.sched.submit(fresh)
 
     # -- crash recovery ------------------------------------------------------
@@ -197,13 +203,15 @@ class ServeEngine:
     def tick(self):
         """One scheduler round: admit, decode, commit-on-cadence.  The
         unit a fleet controller interleaves across engines."""
-        for slot, req in self.sched.admit():
-            self._admit(slot, req)
-        if self.sched.n_running:
-            self._decode_tick()
-        self._tick += 1
-        if self.commit_every and self._tick % self.commit_every == 0:
-            self._commit()
+        with obs.span("serve.tick", tick=self._tick) as sp:
+            for slot, req in self.sched.admit():
+                self._admit(slot, req)
+            sp.set(slots=self.sched.n_running)
+            if self.sched.n_running:
+                self._decode_tick()
+            self._tick += 1
+            if self.commit_every and self._tick % self.commit_every == 0:
+                self._commit()
 
     def run(self, requests: Optional[Sequence[Request]] = None
             ) -> ServeResult:
@@ -235,6 +243,14 @@ class ServeEngine:
 
     def _admit(self, slot: int, req: Request):
         rid = req.rid
+        queued = self._submitted.pop(rid, None)
+        with obs.span("serve.admit", rid=rid, prompt=len(req.prompt),
+                      queued_ms=None if queued is None else
+                      1e3 * (time.perf_counter() - queued)):
+            self._admit_slot(slot, req)
+
+    def _admit_slot(self, slot: int, req: Request):
+        rid = req.rid
         s = self.sessions.get(rid)
         if s is not None and not s.done:
             cache1 = self._resume_cache.pop(rid, None)
@@ -256,7 +272,8 @@ class ServeEngine:
         logits, st = self._prefill(self.params, {"tokens": tokens},
                                    self._caches1)
         self._n_prefills += 1
-        tok0 = int(jnp.argmax(logits, -1)[0])
+        with obs.span("serve.prefill.wait"):
+            tok0 = int(jnp.argmax(logits, -1)[0])
         self.kv.write_slot(slot, st.caches)
         self.pos[slot] = len(s.prompt)
         self.last_token[slot] = tok0
@@ -301,9 +318,10 @@ class ServeEngine:
             self.kv.caches, jnp.asarray(self.pos),
             jnp.asarray(self.active))
         self.kv.caches = new_caches
-        self.pos = np.array(new_pos)      # copy: np.asarray of a jax
-        #                                   array is a read-only view
-        toks = np.asarray(next_toks)
+        with obs.span("serve.decode.wait"):
+            self.pos = np.array(new_pos)  # copy: np.asarray of a jax
+            #                               array is a read-only view
+            toks = np.asarray(next_toks)
         for rid, slot in list(self.sched.running.items()):
             s = self.sessions[rid]
             tok = int(toks[slot])
@@ -330,7 +348,8 @@ class ServeEngine:
 
     def _stage_paged(self, rid: str, cache1: Any):
         """Stage a running session's DIRTY blocks for the next commit —
-        the O(blocks touched) replacement for whole-lane ``store.stage``."""
+        the O(blocks touched) replacement for whole-lane ``store.stage``.
+        ``cache1`` is the slot's cache or the list of its host leaves."""
         s = self.sessions[rid]
         table = self.tables.setdefault(rid, BlockTable())
         for blk, leaves in self.pager.slice_dirty(cache1, s.pos,
@@ -347,9 +366,18 @@ class ServeEngine:
 
     def _commit(self):
         assert self.store is not None
+        with obs.span("serve.commit", tick=self._tick,
+                      sessions=len(self.sessions)):
+            self._commit_sessions()
+
+    def _commit_sessions(self):
         if self.paged:
             for rid, slot in self.sched.running.items():
-                self._stage_paged(rid, self.kv.read_slot(slot))
+                with obs.span("serve.commit.d2h", rid=rid) as sp:
+                    host = self.pager._host_leaves(self.kv.read_slot(slot))
+                    if sp:
+                        sp.set(bytes=sum(a.nbytes for a in host))
+                self._stage_paged(rid, host)
             self.store.commit_paged(self.sessions, self.tables,
                                     self._tick,
                                     block_tokens=self.block_tokens)

@@ -42,6 +42,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.dsm.api import CXL0Context, open_cxl0
 from repro.dsm.pool import (CorruptObjectError, DSMPool, manifest_entry)
 from repro.serve.paging import (BlockPager, BlockRef, BlockTable,
@@ -199,14 +200,18 @@ class SessionStore:
         (repro.dsm.flit_runtime)."""
         if self.committer.complete_fn is None:
             self.committer.complete_fn = self._complete_paged
-        meta = {"kind": "serve", "paged": True, "engine": self.engine_id,
-                "block_tokens": block_tokens,
-                "sessions": {rid: s.to_meta()
-                             for rid, s in sessions.items()},
-                "tables": {rid: t.to_meta() for rid, t in tables.items()}}
-        self._carried = {}
-        for t in tables.values():
-            self._carried.update(t.entries())
+        with obs.span("serve.commit.table", sessions=len(sessions)) as sp:
+            meta = {"kind": "serve", "paged": True,
+                    "engine": self.engine_id,
+                    "block_tokens": block_tokens,
+                    "sessions": {rid: s.to_meta()
+                                 for rid, s in sessions.items()},
+                    "tables": {rid: t.to_meta()
+                               for rid, t in tables.items()}}
+            self._carried = {}
+            for t in tables.values():
+                self._carried.update(t.entries())
+            sp.set(blocks=sum(len(t.refs) for t in tables.values()))
         with self.ctx.commit(step, meta=meta) as txn:
             pass                # dirty blocks were staged via stage_block
         self.absorb_written(tables)

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
+from repro import obs
 from repro.data.pipeline import DataPipeline, PipelineState
 from repro.dsm.api import CXL0Context, open_cxl0
 from repro.dsm.pool import DSMPool
@@ -83,6 +84,15 @@ def _restore_placement(objs, templates):
         return r
     return {name: jax.tree_util.tree_map(place, objs[name], templates[name])
             for name in objs}
+
+
+def _recovered_state(objs, templates, state: TrainState, mesh, step: int):
+    """The train state and pipeline state of recovered objects, leaves
+    back on the mesh when there is one."""
+    with obs.span("train.place", step=step):
+        if mesh is not None:
+            objs = _restore_placement(objs, templates)
+        return _objects_to_state(objs, state)
 
 
 def _objects_to_state(objs, template: TrainState):
@@ -172,9 +182,8 @@ def run_durable_loop(
     if resume:
         try:
             objs, rec_step, source = ctx.recover(templates)
-            if mesh is not None:
-                objs = _restore_placement(objs, templates)
-            state, pipe_state = _objects_to_state(objs, state)
+            state, pipe_state = _recovered_state(objs, templates, state,
+                                                 mesh, rec_step)
             pipeline.state = pipe_state
             recoveries.append(source)
             resumed_from = rec_step
@@ -192,13 +201,14 @@ def run_durable_loop(
     while i < n_steps:
         plan = crash_at.get(i)
         try:
-            t0 = time.perf_counter()
-            batch_np = pipeline.next_global()
-            batch = {k: to_device(v) for k, v in batch_np.items()}
-            new_state, metrics = step_fn(state, batch)
-            state = new_state
-            losses.append(float(metrics["loss"]))
-            t1 = time.perf_counter()
+            with obs.span("train.compute", step=i):
+                t0 = time.perf_counter()
+                batch_np = pipeline.next_global()
+                batch = {k: to_device(v) for k, v in batch_np.items()}
+                new_state, metrics = step_fn(state, batch)
+                state = new_state
+                losses.append(float(metrics["loss"]))
+                t1 = time.perf_counter()
 
             ctx.put(_state_objects(state, pipeline.state), step=i)
 
@@ -225,13 +235,13 @@ def run_durable_loop(
         except CrashError:
             crashes += 1
             crash_at.pop(i, None)
-            ctx.crash()       # f_i: abort in-flight flushes, volatile tiers
-            #                   vanish
+            with obs.span("train.crash", step=i):
+                ctx.crash()   # f_i: abort in-flight flushes, volatile tiers
+                #               vanish
             # --- recovery (new worker incarnation) -------------------------
             objs, rec_step, source = ctx.recover(templates)
-            if mesh is not None:
-                objs = _restore_placement(objs, templates)
-            state, pipe_state = _objects_to_state(objs, state)
+            state, pipe_state = _recovered_state(objs, templates, state,
+                                                 mesh, rec_step)
             pipeline.state = pipe_state
             recoveries.append(source)
             i = rec_step + 1
