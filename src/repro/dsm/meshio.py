@@ -93,6 +93,19 @@ def assemble_leaf(leaf: Any, count: Optional[Callable[[int], None]] = None
     return out
 
 
+def assemble_leaves(leaves: List[Any]) -> List[np.ndarray]:
+    """``assemble_leaf`` over many leaves as ONE batched fetch: every
+    distinct per-device buffer's host copy is started before the first
+    is waited on, so the copies overlap instead of running one round
+    trip each.  Bit-identical to ``[assemble_leaf(l) for l in leaves]``."""
+    for leaf in leaves:
+        if type(leaf) is not np.ndarray \
+                and getattr(leaf, "addressable_shards", None):
+            for s in _unique_shards(leaf):
+                s.data.copy_to_host_async()
+    return [assemble_leaf(leaf) for leaf in leaves]
+
+
 def per_device_nbytes(tree: Any) -> List[int]:
     """Real per-device byte loads of a flush of ``tree``, from sharding
     metadata only: for every leaf, each deduplicated shard's bytes are

@@ -16,8 +16,9 @@ and a fleet controller interleaves many engines' ticks over one pool):
    the allocator and their staged blocks leave the host tier;
 4. **commit** (every ``commit_every`` ticks, durable pools only) — the
    PAGED layout (serve.paging, the default): only the token blocks each
-   session's position touched since the last commit are staged + flushed;
-   the manifest carries every clean block by reference (serve.sessions).
+   session's position touched since the last commit are read off the
+   device, staged + flushed; the manifest carries every clean block by
+   reference (serve.sessions).
    ``paged=False`` keeps the legacy whole-lane path for the equivalence
    tests.
 
@@ -118,7 +119,7 @@ class ServeEngine:
         self.kv = TieredKVCache(bundle, n_slots, t_max,
                                 tiers=store.tiers if store else None,
                                 placement=getattr(store, "placement", None),
-                                parallel=ctx)
+                                parallel=ctx, block_tokens=block_tokens)
         self._caches1 = bundle.init_caches(jax.random.PRNGKey(0), 1, t_max)
         self.sched = SlotScheduler(n_slots)
         self.sessions: Dict[str, Session] = {}
@@ -346,14 +347,15 @@ class ServeEngine:
             else:
                 self.store.discard(rid)
 
-    def _stage_paged(self, rid: str, cache1: Any):
-        """Stage a running session's DIRTY blocks for the next commit —
-        the O(blocks touched) replacement for whole-lane ``store.stage``.
-        ``cache1`` is the slot's cache or the list of its host leaves."""
+    def _stage_paged(self, rid: str, blocks: Dict[int, List[np.ndarray]]
+                     ) -> List[BlockRef]:
+        """Stage a running session's DIRTY blocks (host payloads by block
+        ordinal) for the next commit — the O(blocks touched) replacement
+        for whole-lane ``store.stage``.  Returns the staged refs."""
         s = self.sessions[rid]
         table = self.tables.setdefault(rid, BlockTable())
-        for blk, leaves in self.pager.slice_dirty(cache1, s.pos,
-                                                  table).items():
+        staged = []
+        for blk, leaves in blocks.items():
             ref = table.refs.get(blk)
             if ref is None:
                 ref = BlockRef(blk=blk, bid=self.allocator.alloc(),
@@ -363,6 +365,24 @@ class ServeEngine:
             if blk != STATE_BLOCK:
                 ref.tokens = self.pager.tokens_in_block(blk, s.pos)
             self.store.stage_block(s, ref, leaves)
+            staged.append(ref)
+        return staged
+
+    def _read_dirty(self, rid: str, slot: int
+                    ) -> Dict[int, List[np.ndarray]]:
+        """Host payloads of session ``rid``'s dirty blocks, read off lane
+        ``slot`` on the device: the plan comes from its position and
+        block table, and only the planned spans cross to the host."""
+        table = self.tables.setdefault(rid, BlockTable())
+        plan = self.pager.dirty_blocks(self.sessions[rid].pos, table)
+        with obs.span("serve.commit.d2h", rid=rid) as sp:
+            blocks = self.kv.read_blocks(slot, plan)
+            if sp:
+                sp.set(bytes=sum(a.nbytes for parts in blocks.values()
+                                 for a in parts), blocks=len(plan))
+        return {blk: parts if blk == STATE_BLOCK
+                else self.pager.pad_block(parts)
+                for blk, parts in blocks.items()}
 
     def _commit(self):
         assert self.store is not None
@@ -373,11 +393,7 @@ class ServeEngine:
     def _commit_sessions(self):
         if self.paged:
             for rid, slot in self.sched.running.items():
-                with obs.span("serve.commit.d2h", rid=rid) as sp:
-                    host = self.pager._host_leaves(self.kv.read_slot(slot))
-                    if sp:
-                        sp.set(bytes=sum(a.nbytes for a in host))
-                self._stage_paged(rid, host)
+                self._stage_paged(rid, self._read_dirty(rid, slot))
             self.store.commit_paged(self.sessions, self.tables,
                                     self._tick,
                                     block_tokens=self.block_tokens)
@@ -416,19 +432,9 @@ class ServeEngine:
         RStore each into the TARGET's staging buffer (the hot arm).
         Clean blocks move zero bytes: the target reads them from the pool
         entries the block table already carries."""
-        s = self.sessions[rid]
         table = self.tables[rid]
-        for blk, leaves in self.pager.slice_dirty(cache1, s.pos,
-                                                  table).items():
-            ref = table.refs.get(blk)
-            if ref is None:
-                ref = BlockRef(blk=blk, bid=self.allocator.alloc(),
-                               tokens=0,
-                               name=self.store.block_name(rid, blk))
-                table.refs[blk] = ref
-            if blk != STATE_BLOCK:
-                ref.tokens = self.pager.tokens_in_block(blk, s.pos)
-            self.store.stage_block(s, ref, leaves)
+        for ref in self._stage_paged(rid, self.pager.slice_dirty(
+                cache1, self.sessions[rid].pos, table)):
             self.store.tiers.rstore(ref.name, proxy, tag=tag)
         return table
 

@@ -4,13 +4,24 @@ in the staging/pool tiers.
 The decode batch's caches live as ONE batched pytree on device (the HBM
 tier) with ``n_slots`` lanes on the per-leaf batch axis (layer-stacked
 groups put batch at axis 1 — the axis map comes from the cache
-descriptors via ``train.step.cache_batch_axes``).  Slot surgery is two
-jitted primitives:
+descriptors via ``train.step.cache_batch_axes``).  Slot surgery is jitted
+primitives:
 
 * ``write_slot(slot, cache1)`` — insert a single-sequence cache (fresh
   prefill, or a restored cold session) into a lane;
-* ``read_slot(slot)``         — extract a lane as a single-sequence cache
-  (for spilling, or for staging into a durable commit).
+* ``read_slot(slot)``         — extract a whole lane as a single-sequence
+  cache (legacy whole-lane commits, migration, spilling);
+* ``read_block(slot, lo)``    — extract one token block of a lane:
+  tokens ``[lo, lo + block_tokens)`` of every token-axis leaf, shaped
+  like ``BlockPager.block_template`` (one fixed-shape program whatever
+  the block; a block cut short by the lane's end gets a second one);
+* ``read_state(slot)``        — extract a lane's recurrent-state leaves
+  (no token axis), whole.
+
+``read_blocks(slot, blocks)`` dispatches ``read_block`` per planned
+block (+ ``read_state``) and fetches the results to the host in one
+batched copy: the paged session commit's device-to-host traffic is the
+dirty blocks' bytes, not the lane's.
 
 Cold sessions leave HBM through the CXL0 tiers (``dsm.tiers``):
 
@@ -48,17 +59,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.dsm.meshio import assemble_leaves
 from repro.dsm.pool import manifest_entry, partition_leaves
 from repro.dsm.tiers import TierManager
+from repro.serve.paging import BLOCK_TOKENS, STATE_BLOCK, cache_token_axes
 from repro.train.step import cache_batch_axes
 
 
 class TieredKVCache:
     def __init__(self, bundle, n_slots: int, t_max: int,
                  tiers: Optional[TierManager] = None,
-                 placement=None, parallel=None):
+                 placement=None, parallel=None,
+                 block_tokens: int = BLOCK_TOKENS):
         self.n_slots = n_slots
         self.t_max = t_max
+        self.block_tokens = block_tokens
         self.tiers = tiers
         #: cost-driven spill routing (repro.dsm.placement.PlacementPolicy);
         #: when set, ``spill_auto`` replaces the caller-chosen tier.
@@ -98,6 +113,32 @@ class TieredKVCache:
         self._write = jax.jit(_write, donate_argnums=0)
         self._read = jax.jit(_read)
 
+        batch = [int(a) for a in jax.tree_util.tree_leaves(self.axes)]
+        tok = [int(a) for a in
+               jax.tree_util.tree_leaves(cache_token_axes(bundle))]
+        tok_idx = [i for i, a in enumerate(tok) if a >= 0]
+        state_idx = [i for i, a in enumerate(tok) if a < 0]
+        self._has_state = bool(state_idx)
+
+        def _read_span(full, slot, lo, n):
+            fl = jax.tree_util.tree_leaves(full)
+            out = []
+            for i in tok_idx:
+                start, size = [0] * fl[i].ndim, list(fl[i].shape)
+                start[batch[i]], size[batch[i]] = slot, 1
+                start[tok[i]], size[tok[i]] = lo, n
+                out.append(jax.lax.dynamic_slice(fl[i], start, size))
+            return out
+
+        def _read_state(full, slot):
+            fl = jax.tree_util.tree_leaves(full)
+            return [jax.lax.dynamic_slice_in_dim(fl[i], slot, 1,
+                                                 axis=batch[i])
+                    for i in state_idx]
+
+        self._read_span = jax.jit(_read_span, static_argnums=3)
+        self._read_state = jax.jit(_read_state)
+
     # -- HBM slot surgery ----------------------------------------------------
     def write_slot(self, slot: int, cache1: Any):
         """Insert a single-sequence cache into lane ``slot``."""
@@ -107,6 +148,38 @@ class TieredKVCache:
     def read_slot(self, slot: int) -> Any:
         """Extract lane ``slot`` as a single-sequence cache."""
         return self._read(self.caches, jnp.int32(slot))
+
+    def read_block(self, slot: int, lo: int) -> List[Any]:
+        """Device slices of lane ``slot`` on tokens ``[lo, lo +
+        block_tokens)``, one per token-axis leaf.  The length is fixed,
+        so one compiled program serves every block — except a block the
+        lane's end cuts short (``t_max % block_tokens``), which gets the
+        ``t_max - lo`` tokens there are (``dynamic_slice`` would clamp
+        its start and return the wrong span)."""
+        n = min(self.block_tokens, self.t_max - lo)
+        assert n > 0, (lo, self.t_max)
+        return self._read_span(self.caches, np.int32(slot), np.int32(lo),
+                               n)
+
+    def read_state(self, slot: int) -> List[Any]:
+        """Device copies of lane ``slot``'s recurrent-state leaves."""
+        return self._read_state(self.caches, np.int32(slot))
+
+    def read_blocks(self, slot: int, blocks: List[int]
+                    ) -> Dict[int, List[np.ndarray]]:
+        """Host copies of the token blocks ``blocks`` of lane ``slot``
+        (+ ``STATE_BLOCK``, the recurrent state, when the arch has any):
+        every read is dispatched before ONE batched fetch.  A block cut
+        short by the lane's end comes back short; ``BlockPager.pad_block``
+        pads it."""
+        reads = {blk: self.read_block(slot, blk * self.block_tokens)
+                 for blk in blocks}
+        if self._has_state:
+            reads[STATE_BLOCK] = self.read_state(slot)
+        host = iter(assemble_leaves(
+            [a for parts in reads.values() for a in parts]))
+        return {blk: [next(host) for _ in parts]
+                for blk, parts in reads.items()}
 
     @property
     def template1(self):

@@ -18,7 +18,11 @@ block) into fixed-``block_tokens`` spans:
   IMMUTABLE once the session's position passes its upper edge — a
   session commit re-flushes only the blocks its position touched since
   the last commit (the partial tail + the recurrent STATE block), making
-  cold state O(blocks touched) instead of O(whole cache);
+  cold state O(blocks touched) instead of O(whole cache).  The commit
+  plans those blocks from the position and the block table alone
+  (``BlockPager.dirty_blocks``, no cache bytes) and reads just their
+  token spans on the device (``TieredKVCache.read_blocks``), so the
+  device-to-host copy is O(blocks touched) too, never a whole lane;
 * a per-session **block table** (ordinal -> ``BlockRef``) records each
   block's pool object name, version-entry and valid-token count.  The
   table rides in the session-commit manifest meta, and the manifest's
@@ -54,7 +58,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
-from repro.dsm.meshio import assemble_leaf
+from repro.dsm.meshio import assemble_leaves
 
 BLOCK_TOKENS = 16
 #: ordinal of the recurrent-state pseudo-block (leaves with no token
@@ -204,11 +208,15 @@ class BlockTable:
 
 
 class BlockPager:
-    """Host-side slicing/assembly between whole slot caches and token
-    blocks.  Pure numpy — blocks are spilled/restored on the host path
-    anyway (LStore trees are host copies), and host slicing keeps the
-    jitted slot surgery untouched, so the paged engine is bit-identical
-    to the legacy whole-lane path by construction."""
+    """Block geometry, dirty-block planning, and host-side slicing/
+    assembly between whole slot caches and token blocks.  Planning
+    (``dirty_blocks``) reads only positions and block tables; the
+    session commit reads the planned spans on the device
+    (``TieredKVCache.read_blocks``, the same token slices bit for bit)
+    and never slices here.  Host slicing of a whole cache
+    (``slice_dirty``, ``slice_block``) serves the paths that hold one
+    anyway — migration, prefix publication — and restore assembles
+    blocks back into a whole cache."""
 
     def __init__(self, bundle, t_max: int,
                  block_tokens: int = BLOCK_TOKENS):
@@ -257,52 +265,65 @@ class BlockPager:
         leaves = jax.tree_util.tree_leaves(cache1)
         assert len(leaves) == len(self._leaves), \
             (len(leaves), len(self._leaves))
-        # assemble_leaf copies mesh-sharded lanes per device buffer (and
+        # assemble_leaves copies mesh-sharded lanes per device buffer (and
         # passes host/unsharded leaves through np.asarray-equivalently),
         # so paged spills of a device-sharded cache never demand one
         # monolithic transfer — bit-identical output either way
-        return [assemble_leaf(l) for l in leaves]
+        return assemble_leaves(leaves)
 
-    def slice_block(self, host: List[np.ndarray], blk: int
-                    ) -> List[np.ndarray]:
-        """Token slices of block ``blk`` over every token-axis leaf,
-        zero-padded to ``block_tokens`` (uniform shape: one template fits
-        every block incl. the partial tail, and a partial block's unseen
-        positions are zeros in the source cache anyway)."""
+    def pad_block(self, parts: List[np.ndarray]) -> List[np.ndarray]:
+        """Zero-pad a block's token slices to ``block_tokens`` (uniform
+        shape: one template fits every block incl. one cut short by the
+        lane's end, and a partial block's unseen positions are zeros in
+        the source cache anyway)."""
         bt = self.block_tokens
-        lo = blk * bt
         out = []
-        for i in self.tok_idx:
-            a, ax = host[i], self._axes[i]
-            idx = tuple(slice(lo, lo + bt) if j == ax else slice(None)
-                        for j in range(a.ndim))
-            part = a[idx]
+        for i, part in zip(self.tok_idx, parts):
+            ax = self._axes[i]
             if part.shape[ax] < bt:
                 pad = [(0, bt - part.shape[ax]) if j == ax else (0, 0)
-                       for j in range(a.ndim)]
+                       for j in range(part.ndim)]
                 part = np.pad(part, pad)
             out.append(np.ascontiguousarray(part))
         return out
 
+    def slice_block(self, host: List[np.ndarray], blk: int
+                    ) -> List[np.ndarray]:
+        """Token slices of block ``blk`` over every token-axis leaf,
+        zero-padded to ``block_tokens``."""
+        lo = blk * self.block_tokens
+        return self.pad_block([
+            host[i][tuple(slice(lo, lo + self.block_tokens)
+                          if j == self._axes[i] else slice(None)
+                          for j in range(host[i].ndim))]
+            for i in self.tok_idx])
+
     def slice_state(self, host: List[np.ndarray]) -> List[np.ndarray]:
         return [np.ascontiguousarray(host[i]) for i in self.state_idx]
 
+    def dirty_blocks(self, pos: int, table: BlockTable) -> List[int]:
+        """Token blocks needing (re)staging for a commit at position
+        ``pos``: every span the position entered or grew inside since the
+        block was last durable.  Full durable blocks are skipped — the
+        append-only token axis makes them immutable, which is the whole
+        O(blocks touched) claim.  Needs no cache bytes; the STATE
+        pseudo-block (when the arch has one) is always dirty besides."""
+        out = []
+        for blk in range(self.n_blocks(pos)):
+            ref = table.refs.get(blk)
+            if ref is None or ref.entry is None \
+                    or ref.tokens < self.tokens_in_block(blk, pos):
+                out.append(blk)
+        return out
+
     def slice_dirty(self, cache1: Any, pos: int, table: BlockTable
                     ) -> Dict[int, List[np.ndarray]]:
-        """Blocks needing (re)staging for a commit at position ``pos``:
-        every span the position entered or grew inside since the block
-        was last durable, plus the STATE pseudo-block.  Full durable
-        blocks are skipped — the append-only token axis makes them
-        immutable, which is the whole O(blocks touched) claim."""
+        """Host slices of ``dirty_blocks(pos, table)`` out of a whole
+        slot cache, plus the STATE pseudo-block."""
         host = self._host_leaves(cache1)
-        out: Dict[int, List[np.ndarray]] = {}
-        for blk in range(self.n_blocks(pos)):
-            want = self.tokens_in_block(blk, pos)
-            ref = table.refs.get(blk)
-            if ref is not None and ref.entry is not None \
-                    and ref.tokens >= want:
-                continue
-            out[blk] = self.slice_block(host, blk)
+        out: Dict[int, List[np.ndarray]] = {
+            blk: self.slice_block(host, blk)
+            for blk in self.dirty_blocks(pos, table)}
         if self.state_idx:
             out[STATE_BLOCK] = self.slice_state(host)
         return out

@@ -9,11 +9,14 @@ sweeps so the invariants are exercised even where hypothesis is not
 installed.
 """
 import json
+import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.dsm.pool import DSMPool
 from repro.serve.paging import (BLOCK_TOKENS, BlockAllocator, BlockPager,
                                 BlockRef, BlockTable, OutOfBlocksError,
@@ -186,6 +189,117 @@ def test_slice_dirty_skips_clean_full_blocks(smoke):
     assert set(b for b in dirty if b != STATE_BLOCK) == {2}
 
 
+def _random_lanes(kv, seed=0):
+    """Every position of every lane filled with distinct random values,
+    so a wrong span or lane cannot read as zeros that happen to match."""
+    leaves, treedef = jax.tree_util.tree_flatten(kv.caches)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    kv.caches = jax.tree_util.tree_unflatten(treedef, [
+        jax.device_put(jax.random.normal(k, l.shape, jnp.float32)
+                       .astype(l.dtype), l.sharding)
+        for k, l in zip(keys, leaves)])
+
+
+# (arch, t_max, block_tokens, slot, block, prefill length or None,
+#  data x model mesh or None)
+READ_BLOCK_CASES = {
+    "first": ("olmo-1b", 36, 8, 1, 0, None, None),
+    "middle": ("olmo-1b", 36, 8, 2, 2, None, None),
+    "partial_tail": ("olmo-1b", 36, 8, 0, 2, 21, None),
+    "overrun": ("olmo-1b", 36, 8, 2, 4, None, None),
+    "mesh_sharded": ("olmo-1b", 36, 8, 3, 1, None, (2, 4)),
+    "hybrid_state": ("jamba-1.5-large-398b", 36, 8, 1, 3, None, None),
+    "recurrent_only": ("rwkv6-7b", 36, 8, 1, None, None, None),
+}
+
+
+@pytest.mark.parametrize("case", list(READ_BLOCK_CASES))
+def test_read_block_matches_host_slice_of_whole_lane(case, request):
+    """The paged commit's device-side read of one block (+ the recurrent
+    state) is bit-identical to slicing the whole lane on the host — the
+    first, a middle, the partial tail at the position, a block the
+    lane's end cuts short, lanes sharded over a mesh, and
+    recurrent-state archs."""
+    from repro.configs import get_smoke_config
+    from repro.models.registry import build
+    from repro.serve.kvcache import TieredKVCache
+    arch, t_max, bt, slot, blk, plen, mesh = READ_BLOCK_CASES[case]
+    ctx = None
+    if mesh is not None:
+        from repro.launch.mesh import make_mesh
+        from repro.parallel.sharding import ctx_for_mesh
+        request.getfixturevalue("host_devices_8")
+        ctx = ctx_for_mesh(make_mesh(mesh, ("data", "model")))
+    cfg = get_smoke_config(arch)
+    bundle = build(cfg, dec_pos_len=t_max)
+    kv = TieredKVCache(bundle, 4, t_max, parallel=ctx, block_tokens=bt)
+    pager = BlockPager(bundle, t_max, block_tokens=bt)
+    _random_lanes(kv)
+    if plen is not None:
+        toks = jax.random.randint(jax.random.PRNGKey(5), (1, plen), 0,
+                                  cfg.vocab_size)
+        _, st = bundle.prefill(bundle.init_params(jax.random.PRNGKey(0)),
+                               {"tokens": toks},
+                               bundle.init_caches(jax.random.PRNGKey(0), 1,
+                                                  t_max))
+        kv.write_slot(slot, st.caches)
+    if ctx is not None:
+        assert all(not l.sharding.is_fully_replicated
+                   for l in jax.tree_util.tree_leaves(kv.caches))
+    host = pager._host_leaves(kv.read_slot(slot))
+    plan = [] if blk is None else [blk]
+    got = kv.read_blocks(slot, plan)
+    want = {b: pager.slice_block(host, b) for b in plan}
+    if pager.state_idx:
+        want[STATE_BLOCK] = pager.slice_state(host)
+    assert set(got) == set(want) and want
+    for b, parts in got.items():
+        if b != STATE_BLOCK:
+            short = min(bt, t_max - b * bt)
+            assert all(p.shape[ax] == short for p, ax in zip(
+                parts, (pager._axes[i] for i in pager.tok_idx)))
+            parts = pager.pad_block(parts)
+            assert [(p.shape, p.dtype) for p in parts] \
+                == [(t.shape, t.dtype) for t in pager.block_template]
+        assert len(parts) == len(want[b])
+        for x, y in zip(parts, want[b]):
+            assert x.dtype == y.dtype and x.shape == y.shape
+            assert x.tobytes() == y.tobytes()
+
+
+def test_dirty_blocks_agrees_with_slice_dirty_sweep(smoke):
+    """The commit's plan (``dirty_blocks``, from the position and the
+    table alone) names exactly the blocks ``slice_dirty`` slices, and
+    both follow the rule: no ref, no durable entry, or fewer durable
+    tokens than the position now puts in the block."""
+    _, bundle, _, _, t_max = smoke
+    bt = 4
+    pager = BlockPager(bundle, t_max, block_tokens=bt)
+    cache1 = _filled_cache1(smoke, plen=16)
+    rng = np.random.default_rng(7)
+    for pos in range(0, t_max + 1):
+        for _ in range(4):
+            table = BlockTable()
+            for blk in range(pager.n_blocks(t_max)):
+                kind = rng.integers(0, 3)
+                if kind == 0:
+                    continue
+                table.refs[blk] = BlockRef(
+                    blk=blk, bid=blk, tokens=int(rng.integers(0, bt + 1)),
+                    name=f"kv/r/b{blk}",
+                    entry=None if kind == 1 else
+                    {"name": f"kv/r/b{blk}", "version": 1, "crc": 0})
+            plan = pager.dirty_blocks(pos, table)
+            sliced = pager.slice_dirty(cache1, pos, table)
+            assert plan == sorted(b for b in sliced if b != STATE_BLOCK)
+            rule = [b for b in range(pager.n_blocks(pos))
+                    if b not in table.refs
+                    or table.refs[b].entry is None
+                    or table.refs[b].tokens
+                    < pager.tokens_in_block(b, pos)]
+            assert plan == rule
+
+
 def test_prefix_hash_is_prefix_stable():
     a = prefix_hash("k", [1, 2, 3, 4], 4)
     assert prefix_hash("k", [1, 2, 3, 4], 4) == a
@@ -271,6 +385,57 @@ def test_paged_commit_is_o_blocks_touched(smoke, tmp_path):
                == e["version"]]
     assert carried, "no clean block carried across commits"
     eng.close()
+
+
+def test_paged_commit_copies_only_dirty_blocks(smoke, tmp_path):
+    """Traced: each ``serve.commit.d2h`` span copies ``blocks`` whole
+    token blocks (+ the recurrent state) — never a whole lane — and the
+    blocks a commit reads are exactly the token blocks it stages."""
+    _, _, _, trace, t_max = smoke
+    eng = _build(smoke, tmp_path / "pool", paged=True, block_tokens=4)
+    pager = eng.pager
+    block_bytes = sum(int(np.prod(t.shape)) * t.dtype.itemsize
+                      for t in pager.block_template)
+    state_bytes = sum(int(np.prod(t.shape)) * t.dtype.itemsize
+                      for t in pager.state_template)
+    lane_bytes = block_bytes * t_max // 4 + state_bytes
+    staged, per_commit = [0], []
+    stage_block, commit = eng.store.stage_block, eng._commit
+
+    def counting_stage(session, ref, leaves):
+        staged[0] += ref.blk != STATE_BLOCK
+        return stage_block(session, ref, leaves)
+
+    def counting_commit():
+        staged[0] = 0
+        commit()
+        per_commit.append(staged[0])
+
+    eng.store.stage_block = counting_stage
+    eng._commit = counting_commit
+    eng.submit(trace)
+    t0 = time.perf_counter()
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        for _ in range(10):
+            eng.tick()
+    got = obs.spans(t0, time.perf_counter())
+    eng.close()
+    assert got is not None
+    commits = sorted((s for s in got if s.name == "serve.commit"),
+                     key=lambda s: s.t0)
+    assert len(commits) == len(per_commit) == 5
+    reads = [s for s in got if s.name == "serve.commit.d2h"]
+    for s in reads:
+        assert s.attrs["bytes"] \
+            == s.attrs["blocks"] * block_bytes + state_bytes
+        assert s.attrs["bytes"] < lane_bytes
+    for c, n_staged in zip(commits, per_commit):
+        assert sum(s.attrs["blocks"] for s in reads
+                   if s.parent == c.id) == n_staged
+    assert sum(per_commit) > 0
+    # a session already durable at the previous commit re-reads only
+    # its growing tail block
+    assert min(s.attrs["blocks"] for s in reads) == 1
 
 
 def test_paged_resume_bit_identical(smoke, tmp_path):
