@@ -10,9 +10,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
+import re
 import sys
 import time
 from pathlib import Path
@@ -74,6 +76,32 @@ def driver_module(name: str):
 
 def metric_reader(name: str) -> Callable:
     return load_module(BENCH_DIR / "metrics" / f"{name}.py").read
+
+
+_MODEL_TYPE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+
+@functools.cache
+def _model_module(kind: str, model_type: str):
+    path = BENCH_DIR / kind / f"{model_type}.py"
+    if not _MODEL_TYPE.match(model_type) or not path.is_file():
+        raise FileNotFoundError(
+            f"model_type {model_type!r} needs the file {kind}/{model_type}.py "
+            f"in {BENCH_DIR}, which is not there")
+    return load_module(path, f"chip_{kind}_{model_type}")
+
+
+def arch(cfg_file: dict):
+    """The module ``archs/<model_type>.py`` of a configuration file: the
+    program's configuration, the seeded weights and the counts of its
+    architecture (the interface is set out in ``archs/olmo.py``)."""
+    return _model_module("archs", cfg_file["model_type"])
+
+
+def reference(cfg_file: dict):
+    """The module ``reference/<model_type>.py``: the plain float32
+    implementation that ``correct`` compares with."""
+    return _model_module("reference", cfg_file["model_type"])
 
 
 def cell_metrics(spec: dict, cell: str, trace: bool) -> List[dict]:
@@ -221,30 +249,10 @@ class Window:
 
 # -- the configuration as the program runs it ---------------------------------
 
-#: published key -> the program's ModelConfig field
-_KEYS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-         "num_hidden_layers": "n_layers", "num_attention_heads": "n_heads",
-         "num_key_value_heads": "n_kv_heads", "vocab_size": "vocab_size",
-         "rope_theta": "rope_theta", "tie_word_embeddings":
-         "tied_embeddings", "torch_dtype": "param_dtype"}
-
-
 def program_config(cfg_file: dict):
-    """The program's ModelConfig for a configuration file: its registry
-    entry for ``arch`` (the smoke-size entry for the self-tests' files) at
-    the file's depth.  Every other shape key has to agree already; a
-    disagreement is an error, not a silent change."""
-    from repro.configs import get_config, get_smoke_config
-    base = (get_smoke_config if cfg_file.get("smoke") else get_config)(
-        cfg_file["arch"])
-    cfg = base.with_(
-        n_layers=cfg_file["num_hidden_layers"])
-    bad = {k: (cfg_file[k], getattr(cfg, f)) for k, f in _KEYS.items()
-           if cfg_file[k] != getattr(cfg, f)}
-    if bad or cfg.norm != "nonparametric_ln" or not cfg.glu:
-        raise ValueError(f"{cfg_file['name']}: the program's "
-                         f"{cfg_file['arch']} differs from the file: {bad}")
-    return cfg
+    """The program's ModelConfig for a configuration file, checked against
+    the file by its architecture's module."""
+    return arch(cfg_file).program_config(cfg_file)
 
 
 # -- seeds and weights --------------------------------------------------------
@@ -262,23 +270,29 @@ def prng_key(seed: int, stream: int = 0):
             return key
 
 
-def make_params(abstract_tree, seed: int, scale: float):
+def make_params(abstract_tree, seed: int, scale: float,
+                init: Optional[Callable] = None):
     """Weights for every leaf of ``abstract_tree`` (ShapeDtypeStructs),
     drawn N(0, scale) from the seed on the device, in one jitted call, in
     each leaf's own dtype.  Neither the program under test nor its
     initializer makes them, so the reference can start from the same
-    numbers."""
+    numbers.  ``init(path, leaf, key)``, where given, returns a leaf's
+    own initial value (float32, from ``key``), or None for the normal
+    draw; ``path`` is the leaf's ``jax.tree_util.keystr``."""
     import jax
     import jax.numpy as jnp
-    leaves, treedef = jax.tree_util.tree_flatten(abstract_tree)
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(abstract_tree)
 
     @jax.jit
     def make(key):
         out = []
-        for i, leaf in enumerate(leaves):
+        for i, (path, leaf) in enumerate(leaves):
             k = jax.random.fold_in(key, i)
-            out.append((jax.random.normal(k, leaf.shape, jnp.float32)
-                        * scale).astype(leaf.dtype))
+            x = None if init is None else init(
+                jax.tree_util.keystr(path), leaf, k)
+            if x is None:
+                x = jax.random.normal(k, leaf.shape, jnp.float32) * scale
+            out.append(x.astype(leaf.dtype))
         return out
 
     made = make(prng_key(seed, stream=1))

@@ -62,9 +62,8 @@ class Server:
         check_mix(mix, mix["t_max"])
         self.bundle = build(self.cfg, dec_pos_len=mix["t_max"])
         if params is None:
-            params = chiplib.make_params(self.bundle.abstract_params(),
-                                         a.seed,
-                                         a.config["initializer_range"])
+            params = chiplib.arch(a.config).make_params(
+                self.bundle.abstract_params(), a.seed, a.config)
         durable = mix.get("durable")
         self.pool = None
         dsm = None
@@ -110,7 +109,7 @@ class Server:
             eng.tick()
         t_b = time.perf_counter()
         prefill, decode, keys = 0.0, 0.0, 0
-        n_tokens = slots = 0
+        n_tokens = slots = decode_slots = 0
         for rid, seen in list(self.live.items()):
             s = eng.sessions.get(rid)
             done = rid in eng.results
@@ -127,6 +126,7 @@ class Server:
             self.tokens[rid].extend([t_b] * (n - seen))
             n_tokens += n - seen
             slots += n > seen
+            decode_slots += n > max(seen, 1)       # a token past the first
             if done:
                 del self.live[rid]
             else:
@@ -134,6 +134,7 @@ class Server:
         self.ticks.append({"t0": t_a, "t1": t_b,
                            "commit": eng._n_commits != commits0,
                            "tokens": n_tokens, "slots": slots,
+                           "decode_slots": decode_slots,
                            "prefill_flops": prefill,
                            "decode_flops": decode, "decode_keys": keys})
 
@@ -295,15 +296,15 @@ def pool_mismatches(srv: Server) -> tuple:
 def reference_gaps(a: chiplib.RunArgs, picks, quant=None):
     """Widest gap of the served tokens (and of the quantized reference's
     own picks, with ``quant``) over the sampled requests."""
-    from reference import olmo
     from repro.models.registry import build
+    ref = chiplib.reference(a.config)
     cfg = chiplib.program_config(a.config)
     abstract = build(cfg, dec_pos_len=a.traffic["t_max"]).abstract_params()
-    params = olmo.to_f32(chiplib.make_params(
-        abstract, a.seed, a.config["initializer_range"]))
+    params = ref.to_f32(chiplib.arch(a.config).make_params(
+        abstract, a.seed, a.config))
     worst, worst_ctrl, n = 0.0, None, 0
     for prompt, served in picks:
-        g, c = olmo.served_gaps(a.config, params, prompt, served, quant)
+        g, c = ref.served_gaps(a.config, params, prompt, served, quant)
         worst = max(worst, float(g.max()))
         n += len(served)
         if c is not None:

@@ -110,8 +110,8 @@ class Job:
         self.step_jit = jax.jit(make_train_step(
             bundle, pctx, peak_lr=mix["peak_lr"],
             total_steps=mix["total_steps"]))
-        params = chiplib.make_params(bundle.abstract_params(), a.seed,
-                                     a.config["initializer_range"])
+        params = chiplib.arch(a.config).make_params(
+            bundle.abstract_params(), a.seed, a.config)
         params = jax.tree_util.tree_map(jax.device_put, params,
                                         shardings_for(pctx, bundle.descs))
         self.init_state = init_train_state(
@@ -266,14 +266,13 @@ def program_numbers(job: Job) -> dict:
 def reference_numbers(a: chiplib.RunArgs, quant=None,
                       drop_half: bool = False) -> dict:
     """The plain float32 reference over the same weights and rows."""
-    from reference import olmo
     from repro.data.pipeline import DataPipeline, PipelineState
     from repro.models.registry import build
     mix = a.traffic
+    ref = chiplib.reference(a.config)
     cfg = chiplib.program_config(a.config)
-    params = olmo.to_f32(chiplib.make_params(
-        build(cfg).abstract_params(), a.seed,
-        a.config["initializer_range"]))
+    params = ref.to_f32(chiplib.arch(a.config).make_params(
+        build(cfg).abstract_params(), a.seed, a.config))
     feed = DataPipeline(TrainRows(cfg.vocab_size), mix["global_batch"],
                         mix["seq_len"],
                         state=PipelineState(seed=int(a.seed), step=0))
@@ -281,7 +280,7 @@ def reference_numbers(a: chiplib.RunArgs, quant=None,
     for _ in range(3):
         b = feed.next_global()
         batches.append((b["tokens"], b["targets"]))
-    losses, grad, change = olmo.train(
+    losses, grad, change = ref.train(
         a.config, params, batches, opt_settings(mix), quant=quant,
         store_dtype=a.config["torch_dtype"],
         drop_half=drop_half)

@@ -107,6 +107,7 @@ def decode_roofline_pct(run: Run, programs) -> Optional[float]:
     ticks = [t for t in window_ticks(run) if t["decode_keys"]]
     if not device or not ticks:
         return None
-    least = sum(flops.decode_min_bytes(run.config, t["decode_keys"])
+    least = sum(flops.decode_min_bytes(run.config, t["decode_keys"],
+                                       decode_slots=t["decode_slots"])
                 for t in ticks) / run.peak["hbm_bytes_per_s"]
     return 100.0 * least / sum(device)

@@ -134,12 +134,11 @@ def test_control_fails_the_limits(seed):
     gaps = drv_t.gaps(drv_t.reference_numbers(a, quant="int8"), ref)
     assert any(v > LIMITS["train"][k] for k, v in gaps.items()), gaps
 
-    from reference import olmo
     from repro.models.registry import build
+    olmo = chiplib.reference(CFG_FILE)
     cfg = chiplib.program_config(CFG_FILE)
-    params = olmo.to_f32(chiplib.make_params(
-        build(cfg, dec_pos_len=96).abstract_params(), seed,
-        CFG_FILE["initializer_range"]))
+    params = olmo.to_f32(chiplib.arch(CFG_FILE).make_params(
+        build(cfg, dec_pos_len=96).abstract_params(), seed, CFG_FILE))
     rng = np.random.default_rng(seed)
     worst = 0.0
     for _ in range(6):

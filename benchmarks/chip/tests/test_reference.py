@@ -26,8 +26,8 @@ T_MAX = 48
 
 @pytest.fixture(scope="module")
 def setup():
-    from reference import olmo
     from repro.models.registry import build
+    olmo = chiplib.reference(CFG_FILE)
     cfg = chiplib.program_config(CFG_FILE).with_(
         param_dtype="float32", compute_dtype="float32")
     bundle = build(cfg, dec_pos_len=T_MAX)

@@ -104,6 +104,8 @@ def test_cell_files_found_by_name(cell):
         assert callable(chiplib.metric_reader(m["name"]))
     assert chiplib.limits_file(cell), f"no limits for {cell}"
     assert cfg["name"] == w["config"]
+    assert callable(chiplib.arch(cfg).program_config)
+    assert callable(chiplib.reference(cfg).served_gaps)
 
 
 @pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda c: c["name"])
