@@ -39,11 +39,18 @@ def chunked_attention(
     chunk: int,
     unroll: bool = False,
     softmax_scale: Optional[float] = None,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:                       # (B, S, K, G, hd_v)
+    """``layer``: the kv leaves are a group's layer stack (R, B, T, ...) and
+    attention reads layer ``layer`` of it chunk by chunk, straight from the
+    stack, with no copy of the layer's lanes."""
     B, S, K, G, hd_k = q.shape
-    T = jax.tree_util.tree_leaves(kv)[0].shape[1]
+    T = jax.tree_util.tree_leaves(kv)[0].shape[1 if layer is None else 2]
     chunk = min(chunk, T)
     T_valid = T
+    if T % chunk and layer is not None:    # the padding copies the lanes
+        kv = jax.tree_util.tree_map(lambda a: a[layer], kv)
+        layer = None
     if T % chunk:                      # pad KV to a chunk multiple; padded
         pad = chunk - T % chunk        # positions are masked out below
         kv = jax.tree_util.tree_map(
@@ -53,16 +60,23 @@ def chunked_attention(
     n_chunks = T // chunk
     scale = softmax_scale if softmax_scale is not None else hd_k ** -0.5
 
+    def read(a, start):                # chunk [start, start + chunk) of a
+        if layer is None:
+            return jax.lax.dynamic_slice_in_dim(a, start, chunk, 1)
+        zeros = (0,) * (a.ndim - 3)
+        return jax.lax.dynamic_slice(
+            a, (layer, 0, start) + zeros,
+            (1, a.shape[1], chunk) + a.shape[3:])[0]
+
     # probe hd_v
-    k0, v0 = expand_fn(jax.tree_util.tree_map(lambda a: a[:, :chunk], kv))
+    k0, v0 = expand_fn(jax.tree_util.tree_map(lambda a: read(a, 0), kv))
     hd_v = v0.shape[-1]
 
     qf = q.astype(jnp.float32) * scale
 
     def body(carry, c):
         m, l, acc = carry
-        kv_c = jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_slice_in_dim(a, c * chunk, chunk, 1), kv)
+        kv_c = jax.tree_util.tree_map(lambda a: read(a, c * chunk), kv)
         k_c, v_c = expand_fn(kv_c)
         # scores: (B, K, G, S, Tc)
         s = jnp.einsum("bskgh,btkh->bkgst", qf, k_c.astype(jnp.float32))
@@ -124,6 +138,20 @@ class KVCache(NamedTuple):
     v: jax.Array          # (B, T_max, K, hd)  |  MLA: k_rope (B, T_max, qk_rope)
 
 
+def write_rows(buf: jax.Array, rows: jax.Array, pos: jax.Array,
+               layer: Optional[jax.Array] = None) -> jax.Array:
+    """Write each row's new token ``rows`` (B, 1, ...) into its own lane of
+    ``buf`` (B, T, ...) at its own position ``pos`` (B,), in place.  With
+    ``layer``, ``buf`` is a group's layer stack (R, B, T, ...) and only that
+    layer's rows are written.  A position past the lane writes the lane's
+    last row, as ``dynamic_update_slice`` clamps its start."""
+    rows = rows[:, 0].astype(buf.dtype)
+    lanes = jnp.arange(rows.shape[0])
+    idx = (lanes, pos) if layer is None else (layer, lanes, pos)
+    return buf.at[idx].set(rows, mode="clip", indices_are_sorted=True,
+                           unique_indices=True)
+
+
 def gqa_cache_desc(cfg: ModelConfig, batch: int, t_max: int):
     shape = (batch, t_max, cfg.n_kv_heads, cfg.head_dim)
     dt = cfg.cache_dtype or cfg.compute_dtype
@@ -167,17 +195,26 @@ def gqa_forward(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
     return jnp.einsum("bskgh,kghd->bsd", out, p["wo"])
 
 
+def _row_positions(x: jax.Array, pos: jax.Array) -> jax.Array:
+    """(B,) int32 decode position of each row; a scalar is broadcast."""
+    return jnp.broadcast_to(pos, (x.shape[0],)).astype(jnp.int32)
+
+
 def gqa_decode(cfg: ModelConfig, p, x: jax.Array, cache: KVCache,
-               pos: jax.Array, *, unroll: bool = False):
-    """One-token decode. x: (B, 1, D); pos: scalar int32 current position."""
-    positions = jnp.broadcast_to(pos[None], (x.shape[0], 1)).astype(jnp.int32)
+               pos: jax.Array, *, unroll: bool = False,
+               layer: Optional[jax.Array] = None):
+    """One-token decode. x: (B, 1, D); pos: (B,) int32 position of each row
+    (a scalar is broadcast).  ``cache`` is this layer's (B, T, ...) KV or,
+    with ``layer``, its group's stacked (R, B, T, ...) KV; either way only
+    the new row of each lane is written (``write_rows``)."""
+    pos = _row_positions(x, pos)
+    positions = pos[:, None]
     q, k, v = _project_qkv(cfg, p, x, positions)
-    new_cache = KVCache(
-        k=jax.lax.dynamic_update_slice_in_dim(cache.k, k.astype(cache.k.dtype), pos, 1),
-        v=jax.lax.dynamic_update_slice_in_dim(cache.v, v.astype(cache.v.dtype), pos, 1))
+    new_cache = KVCache(k=write_rows(cache.k, k, pos, layer),
+                        v=write_rows(cache.v, v, pos, layer))
     out = chunked_attention(
         q, (new_cache.k, new_cache.v), lambda kv: kv, positions, 0,
-        causal=True, chunk=cfg.attn_chunk, unroll=unroll)
+        causal=True, chunk=cfg.attn_chunk, unroll=unroll, layer=layer)
     return jnp.einsum("bskgh,kghd->bsd", out, p["wo"]), new_cache
 
 
@@ -260,10 +297,13 @@ def mla_forward(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
 
 
 def mla_decode(cfg: ModelConfig, p, x: jax.Array, cache: KVCache,
-               pos: jax.Array, *, unroll: bool = False):
-    """Absorbed MLA decode: attention in latent space; K=1 shared head."""
+               pos: jax.Array, *, unroll: bool = False,
+               layer: Optional[jax.Array] = None):
+    """Absorbed MLA decode: attention in latent space; K=1 shared head.
+    ``pos`` and ``layer`` as in ``gqa_decode``."""
     m = cfg.mla
-    positions = jnp.broadcast_to(pos[None], (x.shape[0], 1)).astype(jnp.int32)
+    pos = _row_positions(x, pos)
+    positions = pos[:, None]
     q_nope, q_rope = _mla_q(cfg, p, x, positions)
     # absorb w_uk: q' = q_nope @ w_uk^T -> latent width
     q_lat = jnp.einsum("bshq,rhq->bshr", q_nope, p["w_uk"])
@@ -271,9 +311,8 @@ def mla_decode(cfg: ModelConfig, p, x: jax.Array, cache: KVCache,
     q_cat = q_cat[:, :, None, :, :]                        # (B,1,K=1,G=H,·)
 
     ckv, k_rope = _mla_ckv(cfg, p, x, positions)
-    new_cache = KVCache(
-        k=jax.lax.dynamic_update_slice_in_dim(cache.k, ckv.astype(cache.k.dtype), pos, 1),
-        v=jax.lax.dynamic_update_slice_in_dim(cache.v, k_rope.astype(cache.v.dtype), pos, 1))
+    new_cache = KVCache(k=write_rows(cache.k, ckv, pos, layer),
+                        v=write_rows(cache.v, k_rope, pos, layer))
 
     def expand(kv_c):
         ckv_c, kr_c = kv_c
@@ -285,6 +324,6 @@ def mla_decode(cfg: ModelConfig, p, x: jax.Array, cache: KVCache,
     out_lat = chunked_attention(
         q_cat, (new_cache.k, new_cache.v), expand, positions, 0,
         causal=True, chunk=cfg.attn_chunk, unroll=unroll,
-        softmax_scale=qk ** -0.5)                          # (B,1,1,H,r)
+        softmax_scale=qk ** -0.5, layer=layer)             # (B,1,1,H,r)
     out = jnp.einsum("bskhr,rhv->bshv", out_lat, p["w_uv"])
     return jnp.einsum("bshv,hvd->bsd", out, p["wo"]), new_cache

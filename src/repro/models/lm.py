@@ -169,17 +169,39 @@ def _constrain(x, spec: Optional[P], ctx):
     return jax.lax.with_sharding_constraint(x, NamedSharding(ctx.mesh, spec))
 
 
+def _take_layer(cache, layer):
+    """One layer's cache out of its group's stack (``layer`` None: the
+    cache is not stacked)."""
+    if layer is None:
+        return cache
+    return jax.tree_util.tree_map(lambda a: a[layer], cache)
+
+
+def _put_layer(stack, layer, new):
+    """Rewrite one layer of the stack whole, in place: recurrent state has
+    no token axis, and the token rewrites all of it."""
+    if layer is None:
+        return new
+    return jax.tree_util.tree_map(lambda s, n: s.at[layer].set(n), stack, new)
+
+
 def block_forward(cfg: ModelConfig, kind: Tuple[str, str], p, x, positions,
                   *, ctx=None, cache=None, pos=None, decode: bool = False,
-                  moe_mode: str = "a2a", unroll: bool = False):
-    """One transformer block. Returns (x, new_cache, aux_loss)."""
+                  moe_mode: str = "a2a", unroll: bool = False, layer=None):
+    """One transformer block. Returns (x, new_cache, aux_loss).
+
+    At decode ``pos`` is (B,), each row's position, and with ``layer`` the
+    cache is the group's layer stack, carried through the layer loop and
+    written in place: attention K/V (leaves with a ``seq_kv`` axis) get
+    each row's new token at its position, recurrent state is rewritten
+    whole for this layer."""
     mixer, mlp = kind
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
 
     if mixer == "rwkv":
         h = common.apply_norm(cfg, p["norm1"], x)
-        tm_cache = cache if cache is not None else None
+        tm_cache = _take_layer(cache, layer)
         y, (last_tm, S_last) = rwkv_mod.rwkv_time_mix(
             cfg, p["rwkv"], h, tm_cache, unroll=unroll)
         x = x + y
@@ -188,9 +210,10 @@ def block_forward(cfg: ModelConfig, kind: Tuple[str, str], p, x, positions,
             cfg, p["rwkv"], h2, tm_cache)
         x = x + y2
         if cache is not None:
-            new_cache = RWKVCache(last_tm=last_tm.astype(cache.last_tm.dtype),
-                                  last_cm=last_cm.astype(cache.last_cm.dtype),
-                                  S=S_last)
+            new_cache = _put_layer(cache, layer, RWKVCache(
+                last_tm=last_tm.astype(tm_cache.last_tm.dtype),
+                last_cm=last_cm.astype(tm_cache.last_cm.dtype),
+                S=S_last))
         return x, new_cache, aux
 
     # -- sequence mixer -----------------------------------------------------
@@ -216,12 +239,9 @@ def block_forward(cfg: ModelConfig, kind: Tuple[str, str], p, x, positions,
         h = _constrain(h, P(dp, None, None), ctx)
     if mixer == "attn":
         if decode:
-            if cfg.mla:
-                y, new_cache = attention.mla_decode(cfg, p["attn"], h, cache,
-                                                    pos, unroll=unroll)
-            else:
-                y, new_cache = attention.gqa_decode(cfg, p["attn"], h, cache,
-                                                    pos, unroll=unroll)
+            dec = attention.mla_decode if cfg.mla else attention.gqa_decode
+            y, new_cache = dec(cfg, p["attn"], h, cache, pos, unroll=unroll,
+                               layer=layer)
         else:
             if cfg.mla:
                 y = attention.mla_forward(cfg, p["attn"], h, positions,
@@ -245,7 +265,9 @@ def block_forward(cfg: ModelConfig, kind: Tuple[str, str], p, x, positions,
     elif mixer == "mamba":
         initial = cache if cache is not None else None
         if decode:
-            y, new_cache = mamba_mod.mamba_decode(cfg, p["mamba"], h, cache)
+            y, nc = mamba_mod.mamba_decode(cfg, p["mamba"], h,
+                                           _take_layer(cache, layer))
+            new_cache = _put_layer(cache, layer, nc)
         else:
             y, new_cache_full = mamba_mod.mamba_forward(
                 cfg, p["mamba"], h, unroll=unroll, initial=initial)
@@ -259,6 +281,12 @@ def block_forward(cfg: ModelConfig, kind: Tuple[str, str], p, x, positions,
     h2 = common.apply_norm(cfg, p["norm2"], x)
     if mlp == "dense":
         y2 = common.apply_mlp(cfg, p["mlp"], h2)
+    elif decode:
+        # each row's token is routed alone: a capacity shared over the
+        # batch would let one slot's routing drop another slot's token
+        y2, aux = jax.vmap(lambda r: moe_mod.moe_forward(
+            cfg, p["moe"], r[None], parallel=ctx, mode=moe_mode))(h2)
+        y2, aux = y2[:, 0], jnp.mean(aux)
     else:
         y2, aux = moe_mod.moe_forward(cfg, p["moe"], h2, parallel=ctx,
                                       mode=moe_mode)
@@ -304,7 +332,7 @@ def _run_groups(cfg: ModelConfig, params, x, positions, *, ctx, caches=None,
         gp = params["groups"][gi]["blocks"]
         gc = caches[gi]["blocks"] if caches is not None else None
 
-        def superblock(x, blk_params, blk_caches):
+        def superblock(x, blk_params, blk_caches, layer=None):
             aux_sb = jnp.zeros((), jnp.float32)
             out_caches = []
             for pi, kind in enumerate(g.kinds):
@@ -312,7 +340,7 @@ def _run_groups(cfg: ModelConfig, params, x, positions, *, ctx, caches=None,
                 x, nc, aux = block_forward(
                     cfg, kind, blk_params[pi], x, positions, ctx=ctx,
                     cache=c, pos=pos, decode=decode, moe_mode=moe_mode,
-                    unroll=unroll)
+                    unroll=unroll, layer=layer)
                 x = _constrain(x, spec, ctx)
                 out_caches.append(nc)
                 aux_sb = aux_sb + aux
@@ -323,6 +351,24 @@ def _run_groups(cfg: ModelConfig, params, x, positions, *, ctx, caches=None,
             aux_total = aux_total + aux
             if new_caches is not None:
                 new_caches.append({"blocks": ncs})
+        elif decode:
+            # the layer stack rides in the loop carry and each layer writes
+            # its rows in place: no per-layer slice, no rebuilt stack
+            if unroll_layers:
+                for r in range(g.n_repeats):
+                    blk_params = jax.tree_util.tree_map(lambda a: a[r], gp)
+                    x, gc, aux = superblock(x, blk_params, gc, layer=r)
+                    aux_total = aux_total + aux
+            else:
+                def decode_body(carry, blk_params):
+                    x, aux_acc, stacks, r = carry
+                    x, stacks, aux = superblock(x, blk_params, stacks,
+                                                layer=r)
+                    return (x, aux_acc + aux, stacks, r + 1), None
+
+                (x, aux_total, gc, _), _ = jax.lax.scan(
+                    decode_body, (x, aux_total, gc, jnp.int32(0)), gp)
+            new_caches.append({"blocks": gc})
         elif unroll_layers:
             body_fn = superblock
             if with_remat:
@@ -430,7 +476,7 @@ def loss_fn(cfg: ModelConfig, params, batch, *, ctx=None,
 
 class ServeState(NamedTuple):
     caches: Any            # list of group cache dicts
-    pos: jax.Array         # scalar int32: next position to write
+    pos: jax.Array         # int32 next position to write: scalar or (B,)
 
 
 def prefill(cfg: ModelConfig, params, tokens, caches, *, ctx=None,
@@ -454,13 +500,17 @@ def prefill(cfg: ModelConfig, params, tokens, caches, *, ctx=None,
 def decode_step(cfg: ModelConfig, params, tokens, state: ServeState, *,
                 ctx=None, moe_mode: str = "psum", unroll: bool = False,
                 unroll_layers: bool = False):
-    """One decode step. tokens: (B, 1) int32. Returns (logits (B, V), state)."""
+    """One decode step. tokens: (B, 1) int32; ``state.pos`` is a scalar
+    shared by every row or (B,), each row's own position.  Every row writes
+    its token's K/V at its position and attends up to it, independently of
+    the other rows.  Returns (logits (B, V), state)."""
     B = tokens.shape[0]
     pos = state.pos
-    positions = jnp.broadcast_to(pos[None], (B, 1)).astype(jnp.int32)
+    row_pos = jnp.broadcast_to(pos, (B,)).astype(jnp.int32)
     x = embed_inputs(cfg, params, tokens, ctx)
-    x, new_caches, _ = _run_groups(cfg, params, x, positions, ctx=ctx,
-                                   caches=state.caches, pos=pos, decode=True,
+    x, new_caches, _ = _run_groups(cfg, params, x, row_pos[:, None], ctx=ctx,
+                                   caches=state.caches, pos=row_pos,
+                                   decode=True,
                                    moe_mode=moe_mode, unroll=unroll,
                                    unroll_layers=unroll_layers)
     x = common.apply_norm(cfg, params["final_norm"], x)

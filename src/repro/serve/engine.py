@@ -10,7 +10,8 @@ and a fleet controller interleaves many engines' ticks over one pool):
    pool blocks and skips the prefill entirely;
 2. **decode** — one slot-masked batched decode step advances every
    running slot at its own position (``train.step.make_slot_decode_step``
-   — a per-slot vmap, so slot contents never influence each other);
+   — each slot's new K/V row is written in place, and no slot's contents
+   influence another's);
 3. **retire** — sequences that hit their token budget free their slot in
    the same tick (the scheduler contract), their block frames return to
    the allocator and their staged blocks leave the host tier;

@@ -135,7 +135,7 @@ def cache_batch_axes(bundle: ModelBundle):
 
     Layer-stacked groups prepend a ``(repeats,)`` dim to their cache
     leaves, so batch is axis 1 there and axis 0 on singleton groups — any
-    slot-wise cache surgery (vmap, per-slot insert/extract) must be driven
+    slot-wise cache surgery (per-slot insert/extract) must be driven
     by the cache descriptors' logical axis names, not a fixed axis."""
     from repro.models.params import tree_map_descs
     return tree_map_descs(lambda d: d.logical.index("batch"),
@@ -155,35 +155,25 @@ def make_slot_decode_step(bundle: ModelBundle, ctx=None, *,
     * ``active`` (B,) bool — slot occupancy mask,
 
     returns ``(next_tokens (B,), logits (B, V), caches, pos)``; greedy
-    argmax is baked into the graph (the repo's only sampler).  Built as a
-    per-slot ``vmap`` of the single-sequence decode, so each slot's
-    computation is INDEPENDENT of what the other slots hold — outputs do
-    not depend on slot assignment or batch composition, which is what
-    makes crash-replay of a session bit-identical under a different
-    interleaving.  Inactive slots still compute (masked lanes are the
-    price of a fixed batch shape) but their position does not advance and
-    their garbage is overwritten wholesale at the next admission.
+    argmax is baked into the graph (the repo's only sampler).  One batched
+    decode with a position per row: each layer writes only the new token's
+    K/V row of each slot, at that slot's position, in place in the donated
+    cache (``models.lm.block_forward``).  Every row's computation is
+    INDEPENDENT of what the other slots hold — outputs do not depend on
+    slot assignment or batch composition, which is what makes crash-replay
+    of a session bit-identical under a different interleaving.  Inactive
+    slots still compute (masked lanes are the price of a fixed batch shape)
+    but their position does not advance and their garbage is overwritten
+    wholesale at the next admission.
     """
     assert not bundle.cfg.is_encdec, "slot decode is decoder-only"
     from repro.models.lm import ServeState
-    axes = cache_batch_axes(bundle)
-    tree_map = jax.tree_util.tree_map
 
     def slot_decode(params, tokens, caches, pos, active):
-        def one(tok, cache, p):
-            cache1 = tree_map(lambda x, a: jnp.expand_dims(x, a),
-                              cache, axes)
-            logits, st = bundle.decode(params, tok[None],
-                                       ServeState(cache1, p), ctx=ctx,
-                                       moe_mode=moe_mode)
-            nc = tree_map(lambda x, a: jnp.squeeze(x, a), st.caches, axes)
-            return logits[0], nc, st.pos
-
-        logits, new_caches, new_pos = jax.vmap(
-            one, in_axes=(0, axes, 0), out_axes=(0, axes, 0))(
-            tokens, caches, pos)
-        new_pos = jnp.where(active, new_pos, pos)
+        logits, st = bundle.decode(params, tokens, ServeState(caches, pos),
+                                   ctx=ctx, moe_mode=moe_mode)
+        new_pos = jnp.where(active, st.pos, pos)
         next_tokens = jnp.argmax(logits, -1).astype(jnp.int32)
-        return next_tokens, logits, new_caches, new_pos
+        return next_tokens, logits, st.caches, new_pos
 
     return slot_decode
