@@ -153,9 +153,9 @@ class DurableCommitter:
 
     def _hbm_bytes(self) -> int:
         # emu.tree_nbytes is THE byte-counting used everywhere the
-        # placement policy is fed sizes (kvcache.spill_auto, cluster
-        # ranks) — one definition, so the same state never prices
-        # differently across call sites
+        # placement policy is fed sizes (commits, cluster ranks) — one
+        # definition, so the same state never prices differently across
+        # call sites
         from repro.dsm.emu import tree_nbytes
         return tree_nbytes(dict(self.tiers.hbm))
 

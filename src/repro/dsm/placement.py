@@ -1,13 +1,13 @@
 """Cost-driven tier placement under an emulated CXL topology.
 
 The runtime used to hard-code its placement choices: the committer's
-shard count came from ``auto_shard_count`` (device count, topology-blind),
-the KV-cache manager spilled wherever the caller said, and cluster ranks
-ring-staged unconditionally.  ``PlacementPolicy`` replaces those choices
-with cost-model decisions priced by the SAME functions the topology
-emulator uses (repro.dsm.emu), so under ``cxl11-direct`` the policy
-behaves like the calibrated paper pair and under ``cxl30-fabric`` it
-exploits link fan-out — and every decision is logged and assertable.
+shard count came from ``auto_shard_count`` (device count, topology-blind)
+and cluster ranks ring-staged unconditionally.  ``PlacementPolicy``
+replaces those choices with cost-model decisions priced by the SAME
+functions the topology emulator uses (repro.dsm.emu), so under
+``cxl11-direct`` the policy behaves like the calibrated paper pair and
+under ``cxl30-fabric`` it exploits link fan-out — and every decision is
+logged and assertable.
 
 Three decisions, all per object size under the active topology:
 
@@ -29,8 +29,6 @@ Wiring (each opt-in, defaults unchanged):
 
 * ``DurableCommitter(placement=...)`` resolves its shard count — and,
   with ``mode="auto"``, its schedule — from the policy at first commit;
-* ``TieredKVCache(placement=...)`` gains ``spill_auto`` which routes an
-  evicted session cache to staging or (sharded) pool per decision;
 * cluster ranks call ``plan_rank_staging`` to decide whether ring
   RStore-staging their partition every step is worth its cost
   (``scenarios/cluster_worker.py --topology``);

@@ -204,8 +204,8 @@ class TierManager:
 
     def rload(self, name: str) -> Optional[Any]:
         """Read back a value staged INTO this worker's host buffer by a
-        peer's rstore (the staging-tier restore path of the KV-cache
-        manager).  Returns the host tree or None."""
+        peer's rstore (the staging-tier restore path).  Returns the host
+        tree or None."""
         staged = self.staging.get(name)
         return None if staged is None else staged[1]
 

@@ -6,10 +6,9 @@ Three layers, one per module:
   admitted FIFO into fixed decode slots, prefill of new requests
   interleaves with batched decode of running ones, finished sequences
   free their slot immediately (no static-batch stragglers);
-* ``serve.kvcache``   — tiered KV-cache manager: per-slot cache blocks in
-  HBM, cold session caches spilled/restored through ``TierManager``'s
-  host-staging (RStore) and pool (RFlush) tiers with byte-balanced block
-  layout (``partition_leaves``);
+* ``serve.kvcache``   — KV-cache lanes: the decode batch's per-slot
+  caches in HBM, jitted slot surgery, and device-side reads of a lane's
+  dirty token blocks (block geometry from ``serve.paging.BlockPager``);
 * ``serve.sessions``  — durable session store: session state (prompt,
   emitted tokens, KV-cache version) commits through the FliT commit path
   (``dsm.flit_runtime.DurableCommitter``), so a killed serving worker

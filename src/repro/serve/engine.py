@@ -1,4 +1,4 @@
-"""ServeEngine: continuous batching + tiered KV caches + durable sessions.
+"""ServeEngine: continuous batching + slot KV caches + durable sessions.
 
 The serving loop per decode tick (``tick()`` — ``run()`` just loops it,
 and a fleet controller interleaves many engines' ticks over one pool):
@@ -15,13 +15,14 @@ and a fleet controller interleaves many engines' ticks over one pool):
 3. **retire** — sequences that hit their token budget free their slot in
    the same tick (the scheduler contract), their block frames return to
    the allocator and their staged blocks leave the host tier;
-4. **commit** (every ``commit_every`` ticks, durable pools only) — the
-   PAGED layout (serve.paging, the default): only the token blocks each
-   session's position touched since the last commit are read off the
-   device, staged + flushed; the manifest carries every clean block by
-   reference (serve.sessions).
-   ``paged=False`` keeps the legacy whole-lane path for the equivalence
-   tests.
+4. **commit** (every ``commit_every`` ticks, with a session store only)
+   — only the token blocks (serve.paging) each session's position
+   touched since the last commit are read off the device, staged +
+   flushed; the manifest carries every clean block by reference
+   (serve.sessions).
+
+An engine is durable exactly when it is given a ``store``; without one
+it serves the same tokens and commits nothing.
 
 Crash recovery: a restarted worker calls ``resume()`` — finished
 sessions come back as results; running sessions re-enter the admission
@@ -84,7 +85,6 @@ class ServeEngine:
                  commit_every: int = 0,
                  restore_mode: str = "cache",
                  retire_done: bool = False,
-                 paged: bool = True,
                  block_tokens: int = BLOCK_TOKENS,
                  allocator: Optional[BlockAllocator] = None,
                  prefix_reuse: bool = False,
@@ -104,11 +104,10 @@ class ServeEngine:
         self.commit_every = commit_every if store is not None else 0
         self.restore_mode = restore_mode
         self.retire_done = retire_done
-        self.paged = paged and store is not None
         self.block_tokens = block_tokens
         #: reuse is sound only within one model identity: ``prefix_key``
         #: must fold arch + params seed (build_serve_engine sets it)
-        self.prefix_reuse = prefix_reuse and self.paged
+        self.prefix_reuse = prefix_reuse and store is not None
         self.prefix_key = prefix_key
 
         prefill_step, decode_step = make_serve_steps(bundle, ctx)
@@ -117,10 +116,11 @@ class ServeEngine:
         self._slot_decode = jax.jit(make_slot_decode_step(bundle, ctx),
                                     donate_argnums=(2,))
 
-        self.kv = TieredKVCache(bundle, n_slots, t_max,
-                                tiers=store.tiers if store else None,
-                                placement=getattr(store, "placement", None),
-                                parallel=ctx, block_tokens=block_tokens)
+        self.pager = BlockPager(bundle, t_max, block_tokens)
+        frames = n_slots * (self.pager.n_blocks(t_max) + 1) + 8
+        self.allocator = allocator or BlockAllocator(max(64, 4 * frames))
+        self.tables: Dict[str, BlockTable] = {}
+        self.kv = TieredKVCache(bundle, n_slots, self.pager, parallel=ctx)
         self._caches1 = bundle.init_caches(jax.random.PRNGKey(0), 1, t_max)
         self.sched = SlotScheduler(n_slots)
         self.sessions: Dict[str, Session] = {}
@@ -132,11 +132,6 @@ class ServeEngine:
         #: target never committed its adoption — the fleet resume
         #: completes these (serve.fleet.FleetController.resume)
         self._handoffs: Dict[str, Optional[BlockTable]] = {}
-        if self.paged:
-            self.pager = BlockPager(bundle, t_max, block_tokens)
-            frames = n_slots * (self.pager.n_blocks(t_max) + 1) + 8
-            self.allocator = allocator or BlockAllocator(max(64, 4 * frames))
-            self.tables: Dict[str, BlockTable] = {}
         # host-side slot state
         self.pos = np.zeros(n_slots, np.int32)
         self.last_token = np.zeros(n_slots, np.int32)
@@ -175,8 +170,7 @@ class ServeEngine:
         (cold pool)."""
         if self.store is None:
             return None
-        rec = self.store.recover(self.kv.template1,
-                                 pager=self.pager if self.paged else None)
+        rec = self.store.recover(pager=self.pager)
         if rec is None:
             return None
         for rid, s in rec.sessions.items():
@@ -190,7 +184,7 @@ class ServeEngine:
                 self.results[rid] = list(s.emitted)
             else:
                 self._resume_cache[rid] = rec.caches.get(rid)
-                if self.paged and rid in rec.tables:
+                if rid in rec.tables:
                     self.tables[rid] = rec.tables[rid]
                     for bid in rec.tables[rid].bids():
                         self.allocator.adopt(bid)
@@ -338,21 +332,17 @@ class ServeEngine:
         s = self.sessions[rid]
         s.done = True
         self.results[rid] = list(s.emitted)
+        t = self.tables.pop(rid, None)
+        if t is not None:
+            for bid in t.bids():
+                self.allocator.free(bid)
         if self.store is not None:
-            if self.paged:
-                t = self.tables.pop(rid, None)
-                if t is not None:
-                    for bid in t.bids():
-                        self.allocator.free(bid)
-                self.store.discard_session_blocks(rid)
-            else:
-                self.store.discard(rid)
+            self.store.discard_session_blocks(rid)
 
-    def _stage_paged(self, rid: str, blocks: Dict[int, List[np.ndarray]]
-                     ) -> List[BlockRef]:
+    def _stage_blocks(self, rid: str, blocks: Dict[int, List[np.ndarray]]
+                      ) -> List[BlockRef]:
         """Stage a running session's DIRTY blocks (host payloads by block
-        ordinal) for the next commit — the O(blocks touched) replacement
-        for whole-lane ``store.stage``.  Returns the staged refs."""
+        ordinal) for the next commit.  Returns the staged refs."""
         s = self.sessions[rid]
         table = self.tables.setdefault(rid, BlockTable())
         staged = []
@@ -392,17 +382,10 @@ class ServeEngine:
             self._commit_sessions()
 
     def _commit_sessions(self):
-        if self.paged:
-            for rid, slot in self.sched.running.items():
-                self._stage_paged(rid, self._read_dirty(rid, slot))
-            self.store.commit_paged(self.sessions, self.tables,
-                                    self._tick,
-                                    block_tokens=self.block_tokens)
-        else:
-            for rid, slot in self.sched.running.items():
-                self.store.stage(self.sessions[rid],
-                                 self.kv.read_slot(slot))
-            self.store.commit(self.sessions, self._tick)
+        for rid, slot in self.sched.running.items():
+            self._stage_blocks(rid, self._read_dirty(rid, slot))
+        self.store.commit_paged(self.sessions, self.tables, self._tick,
+                                block_tokens=self.block_tokens)
         self._n_commits += 1
         if self.retire_done:
             # done sessions were durable in the table just committed;
@@ -434,13 +417,13 @@ class ServeEngine:
         Clean blocks move zero bytes: the target reads them from the pool
         entries the block table already carries."""
         table = self.tables[rid]
-        for ref in self._stage_paged(rid, self.pager.slice_dirty(
+        for ref in self._stage_blocks(rid, self.pager.slice_dirty(
                 cache1, self.sessions[rid].pos, table)):
             self.store.tiers.rstore(ref.name, proxy, tag=tag)
         return table
 
     def commit_handoff(self, rid: str, target_id: int):
-        """mig_commit: mark the session migrated and commit — ONE paged
+        """mig_commit: mark the session migrated and commit — ONE
         commit makes the marker, the block table and the staged dirty
         blocks durable atomically.  After this manifest lands the target
         owns the session, crash or no crash."""
@@ -546,7 +529,6 @@ def build_serve_engine(arch: str = "olmo-1b", *, smoke: bool = True,
                        topology: Optional[str] = None,
                        dsm: Optional["CXL0Config"] = None,
                        engine_id: int = 0,
-                       paged: bool = True,
                        block_tokens: int = BLOCK_TOKENS,
                        allocator: Optional[BlockAllocator] = None,
                        prefix_reuse: bool = False,
@@ -594,7 +576,7 @@ def build_serve_engine(arch: str = "olmo-1b", *, smoke: bool = True,
     engine = ServeEngine(
         bundle, params, n_slots=n_slots, t_max=t_max, ctx=ctx,
         store=store, commit_every=commit_every, restore_mode=restore_mode,
-        retire_done=retire_done, paged=paged, block_tokens=block_tokens,
+        retire_done=retire_done, block_tokens=block_tokens,
         allocator=allocator, prefix_reuse=prefix_reuse,
         prefix_key=f"{arch}|{'smoke' if smoke else 'full'}|s{seed}")
     return engine, cfg

@@ -120,8 +120,8 @@ class FleetController:
             pool_path=pool_path, commit_every=commit_every,
             commit_mode=commit_mode, topology=topology, seed=seed,
             restore_mode=restore_mode, retire_done=retire_done,
-            fault_hook=fault_hook, paged=True,
-            block_tokens=block_tokens, prefix_reuse=prefix_reuse)
+            fault_hook=fault_hook, block_tokens=block_tokens,
+            prefix_reuse=prefix_reuse)
         self._bundle, self._params = bundle, params
         self.engines: Dict[int, ServeEngine] = {}
         self.allocator = allocator

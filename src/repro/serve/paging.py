@@ -1,13 +1,10 @@
-"""Paged KV layout: fixed-size token-axis blocks instead of whole-lane
-pytrees.
+"""Paged KV layout: a session's cache is durable as fixed-size
+token-axis blocks.
 
-The legacy durable-serving path spilled ONE ``kv/<rid>`` object per
-session — the whole per-slot cache pytree, re-flushed at every commit
-even though a decode tick appends exactly one token.  The paged layout
-splits every cache leaf that HAS a token axis (logical axis name
-``seq_kv`` in the cache descriptors — attention K/V; recurrent
-mamba/rwkv state has none and rides in a separate always-dirty STATE
-block) into fixed-``block_tokens`` spans:
+Every cache leaf that HAS a token axis (logical axis name ``seq_kv``
+in the cache descriptors — attention K/V; recurrent mamba/rwkv state
+has none and rides in a separate always-dirty STATE block) is split
+into fixed-``block_tokens`` spans:
 
 * block ``k`` of session ``rid`` covers decode positions
   ``[k*bt, (k+1)*bt)`` and lives in the pool as object
@@ -223,18 +220,23 @@ class BlockPager:
         assert block_tokens >= 1, block_tokens
         self.t_max = t_max
         self.block_tokens = block_tokens
-        template = bundle.abstract_caches(1, t_max)
-        self._leaves, self._treedef = jax.tree_util.tree_flatten(template)
+        #: single-sequence cache pytree prototype (abstract shapes)
+        self.template = bundle.abstract_caches(1, t_max)
+        self._leaves, self._treedef = jax.tree_util.tree_flatten(
+            self.template)
         axes = jax.tree_util.tree_leaves(cache_token_axes(bundle))
         assert len(axes) == len(self._leaves)
-        self._axes = [int(a) for a in axes]
-        self.tok_idx = [i for i, a in enumerate(self._axes) if a >= 0]
-        self.state_idx = [i for i, a in enumerate(self._axes) if a < 0]
+        #: per-leaf token axis (-1: recurrent state) and the leaf indices
+        #: on each side — the one source of block geometry, which the
+        #: device-side block reads (``TieredKVCache``) share bit for bit
+        self.token_axes = [int(a) for a in axes]
+        self.tok_idx = [i for i, a in enumerate(self.token_axes) if a >= 0]
+        self.state_idx = [i for i, a in enumerate(self.token_axes) if a < 0]
 
         def _blk_struct(i):
             l = self._leaves[i]
             shape = list(l.shape)
-            shape[self._axes[i]] = block_tokens
+            shape[self.token_axes[i]] = block_tokens
             return jax.ShapeDtypeStruct(tuple(shape), l.dtype)
 
         #: pytree template of one block object (list of token slices) —
@@ -279,7 +281,7 @@ class BlockPager:
         bt = self.block_tokens
         out = []
         for i, part in zip(self.tok_idx, parts):
-            ax = self._axes[i]
+            ax = self.token_axes[i]
             if part.shape[ax] < bt:
                 pad = [(0, bt - part.shape[ax]) if j == ax else (0, 0)
                        for j in range(part.ndim)]
@@ -294,7 +296,7 @@ class BlockPager:
         lo = blk * self.block_tokens
         return self.pad_block([
             host[i][tuple(slice(lo, lo + self.block_tokens)
-                          if j == self._axes[i] else slice(None)
+                          if j == self.token_axes[i] else slice(None)
                           for j in range(host[i].ndim))]
             for i in self.tok_idx])
 
@@ -343,7 +345,7 @@ class BlockPager:
                 continue
             lo = blk * bt
             for i, part in zip(self.tok_idx, parts):
-                ax = self._axes[i]
+                ax = self.token_axes[i]
                 hi = min(lo + bt, leaves[i].shape[ax])
                 if hi <= lo:
                     continue

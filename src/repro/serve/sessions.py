@@ -2,19 +2,14 @@
 
 One *session commit* at decode tick ``s`` is the paper's Alg. 2 over the
 serving worker's live state, exactly as a training checkpoint commit but
-with a DYNAMIC object set.  Two layouts share the machinery:
-
-* **paged** (the default engine path since the fleet refactor) — one
-  pool object per token BLOCK, ``kv/<rid>/b<k>`` (serve.paging): the
-  commit flushes only the blocks a session's position touched since the
-  last commit, and the manifest's object dict is the union of those
-  fresh flushes and the CARRIED entries of every clean block (merged in
-  a delegated completeOp), so any single manifest still describes every
-  live cache completely.  The per-session block tables ride in the
-  manifest meta next to the session table — tokens, tables and block
-  bytes become durable in ONE atomic rename;
-* **legacy** (kept for the equivalence tests) — one whole-lane
-  ``kv/<rid>`` object per running session, re-flushed every commit.
+with a DYNAMIC object set: one pool object per token BLOCK,
+``kv/<rid>/b<k>`` (serve.paging).  The commit flushes only the blocks a
+session's position touched since the last commit, and the manifest's
+object dict is the union of those fresh flushes and the CARRIED entries
+of every clean block (merged in a delegated completeOp), so any single
+manifest still describes every live cache completely.  The per-session
+block tables ride in the manifest meta next to the session table —
+tokens, tables and block bytes become durable in ONE atomic rename.
 
 A killed serving worker restarts and calls ``recover()``: the newest
 manifest for THIS engine (fleet manifests are tagged ``engine: i`` and
@@ -51,10 +46,6 @@ from repro.serve.paging import (BlockPager, BlockRef, BlockTable,
                                 shared_head_name)
 
 KV_PREFIX = "kv/"
-
-
-def kv_name(rid: str) -> str:
-    return KV_PREFIX + rid
 
 
 def engine_ns(engine_id: int) -> str:
@@ -109,8 +100,8 @@ class RecoveredState:
     caches: Dict[str, Any]           # rid -> restored cache (running only)
     step: int                        # decode tick of the commit
     seq: int                         # manifest sequence
-    #: paged commits only: the recovered per-session block tables (the
-    #: engine re-adopts their frame ids into its allocator)
+    #: the recovered per-session block tables (the engine re-adopts
+    #: their frame ids into its allocator)
     tables: Dict[str, BlockTable] = dataclasses.field(default_factory=dict)
 
 
@@ -132,12 +123,11 @@ class SessionStore:
                             fault_hook=fault_hook, placement=placement)
         self.ctx = ctx
         self.pool = ctx.pool
-        self.placement = ctx.placement  # cost-driven shard count/schedule
         self.recovery = ctx.recovery
         self.engine_id = engine_id
         self.ns = engine_ns(engine_id)
         #: clean-block manifest entries carried into the next completeOp
-        #: (rebuilt from the live block tables at every paged commit)
+        #: (rebuilt from the live block tables at every commit)
         self._carried: Dict[str, dict] = {}
         #: entries of the most recent completeOp's fresh flushes —
         #: captured inside the delegated completeOp, absorbed into block
@@ -155,32 +145,7 @@ class SessionStore:
     def block_name(self, rid: str, blk: int) -> str:
         return block_object_name(rid, blk, self.ns)
 
-    # -- legacy commit side (whole-lane kv/<rid> objects) --------------------
-    def stage(self, session: Session, cache1: Any):
-        """LStore a running session's slot cache for the next commit and
-        record the version it will be durable at."""
-        self.tiers.lstore(self.ns + kv_name(session.rid), cache1)
-        session.cache_version = \
-            self.tiers.versions[self.ns + kv_name(session.rid)]
-
-    def discard(self, rid: str):
-        """Session finished (or evicted): its cache leaves the host tier so
-        the next commit stops flushing it."""
-        self.tiers.ldiscard(self.ns + kv_name(rid))
-
-    def commit(self, sessions: Dict[str, Session], step: int):
-        """Alg. 2 commit as ONE commit region: RFlush every staged cache,
-        then exactly one completeOp manifest carrying the session table."""
-        meta = {"kind": "serve", "engine": self.engine_id,
-                "sessions": {rid: s.to_meta()
-                             for rid, s in sessions.items()}}
-        if not self.engine_id:
-            meta.pop("engine")        # single-engine meta unchanged
-        with self.ctx.commit(step, meta=meta) as txn:
-            pass                # caches were staged via ``stage``
-        return txn.stats
-
-    # -- paged commit side ---------------------------------------------------
+    # -- commit side --------------------------------------------------------
     def stage_block(self, session: Session, ref: BlockRef, leaves):
         """LStore one dirty block payload; the next commit flushes it."""
         self.tiers.lstore(ref.name, leaves)
@@ -320,32 +285,24 @@ class SessionStore:
         self.ctx.close()
 
     # -- recovery side -------------------------------------------------------
-    def _manifests_for_engine(self) -> List[dict]:
-        out = []
+    def recover(self, cache_template=None, *, pager: BlockPager
+                ) -> Optional[RecoveredState]:
+        """Newest fully-valid session commit FOR THIS ENGINE, or None on
+        a cold pool.  Each running session's cache is assembled by
+        ``pager`` from its block table's objects.  Any torn or unreadable
+        object fails the WHOLE manifest and recovery falls back to an
+        older one — a session table can never pair with torn bytes.  A
+        manifest without block tables (no ``paged`` in its meta) is not a
+        session commit and is skipped.  ``cache_template`` is unused; it
+        stays until the chip benchmark's serving driver stops passing
+        it."""
         for m in self.pool.manifests_desc():
             meta = m.get("meta") or {}
-            if "sessions" not in meta:
+            if not meta.get("paged"):
                 continue                      # not a serve commit
             if int(meta.get("engine", 0)) != self.engine_id:
                 continue                      # a fleet sibling's commit
-            out.append(m)
-        return out
-
-    def recover(self, cache_template, *,
-                pager: Optional[BlockPager] = None
-                ) -> Optional[RecoveredState]:
-        """Newest fully-valid session commit FOR THIS ENGINE, or None on
-        a cold pool.  Handles both layouts: paged manifests restore each
-        running session by assembling its block table's objects
-        (``pager`` required); legacy manifests read whole-lane
-        ``kv/<rid>`` objects against ``cache_template``.  Any torn or
-        unreadable object fails the WHOLE manifest and recovery falls
-        back to an older one — a session table can never pair with torn
-        bytes."""
-        for m in self._manifests_for_engine():
-            meta = m.get("meta") or {}
-            got = (self._read_paged(m, meta, pager) if meta.get("paged")
-                   else self._read_legacy(m, meta, cache_template))
+            got = self._read_sessions(m, meta, pager)
             if got is None:
                 continue                      # torn commit: older manifest
             sessions, caches, tables = got
@@ -353,24 +310,7 @@ class SessionStore:
                                   tables=tables)
         return None
 
-    def _read_legacy(self, m: dict, meta: dict, cache_template):
-        sessions = {rid: Session.from_meta(rid, d)
-                    for rid, d in meta["sessions"].items()}
-        caches: Dict[str, Any] = {}
-        try:
-            for name, entry in m["objects"].items():
-                caches[name] = self.pool.read_entry(name, entry,
-                                                    cache_template)
-        except (CorruptObjectError, KeyError, ValueError):
-            return None
-        caches = {rid: caches[self.ns + kv_name(rid)] for rid in sessions
-                  if self.ns + kv_name(rid) in caches}
-        return sessions, caches, {}
-
-    def _read_paged(self, m: dict, meta: dict,
-                    pager: Optional[BlockPager]):
-        if pager is None:
-            return None       # paged pool read without a pager: no match
+    def _read_sessions(self, m: dict, meta: dict, pager: BlockPager):
         sessions = {rid: Session.from_meta(rid, d)
                     for rid, d in meta["sessions"].items()}
         tables = {rid: BlockTable.from_meta(d)

@@ -243,7 +243,7 @@ def test_train_crash_cell(tmp_path, point):
 
 
 # ---------------------------------------------------------------------------
-# SERVE: SessionStore(ctx) == legacy hand-wired commit, bit for bit
+# SERVE: a SessionStore's context == legacy hand-wired commit, bit for bit
 # ---------------------------------------------------------------------------
 
 def serve_caches(tick):
@@ -253,6 +253,12 @@ def serve_caches(tick):
         "s2": {"k": np.arange(8, dtype=np.float32) * 2 + tick,
                "v": np.full(4, 7.0 + tick, np.float32)},
     }
+
+
+#: recovery templates of the two sessions' ``kv/<rid>`` cache objects
+SERVE_TEMPLATES = {f"kv/{rid}": {"k": np.zeros(8, np.float32),
+                                 "v": np.zeros(4, np.float32)}
+                   for rid in ("s1", "s2")}
 
 
 def serve_table(store_like_versions, tick):
@@ -266,8 +272,13 @@ def serve_table(store_like_versions, tick):
     return table
 
 
+def serve_meta(table):
+    return {"kind": "serve",
+            "sessions": {rid: s.to_meta() for rid, s in table.items()}}
+
+
 def test_serve_equivalence_bit_identical(tmp_path):
-    from repro.serve.sessions import SessionStore, kv_name
+    from repro.serve.sessions import SessionStore
 
     # -- legacy: hand-wired tiers + committer, meta assembled by hand ----
     pool_l = DSMPool(str(tmp_path / "legacy"))
@@ -277,42 +288,40 @@ def test_serve_equivalence_bit_identical(tmp_path):
         caches = serve_caches(tick)
         versions = {}
         for rid, c in caches.items():
-            tiers.lstore(kv_name(rid), c)
-            versions[rid] = tiers.versions[kv_name(rid)]
-        table = serve_table(versions, tick)
-        meta = {"kind": "serve",
-                "sessions": {rid: s.to_meta() for rid, s in table.items()}}
-        committer.commit(tick, meta=meta)
+            tiers.lstore(f"kv/{rid}", c)
+            versions[rid] = tiers.versions[f"kv/{rid}"]
+        committer.commit(tick, meta=serve_meta(serve_table(versions, tick)))
     tiers.close()
 
-    # -- new API: the migrated SessionStore over an open_cxl0 context ----
+    # -- new API: a SessionStore's open_cxl0 context, one commit region --
     store = SessionStore(DSMPool(str(tmp_path / "ctx")), mode="sync",
                          retention=2)
     for tick in (3, 7):
         caches = serve_caches(tick)
         versions = {}
         for rid, c in caches.items():
-            store.tiers.lstore(kv_name(rid), c)
-            versions[rid] = store.tiers.versions[kv_name(rid)]
-        table = serve_table(versions, tick)
-        store.commit(table, tick)
+            store.tiers.lstore(f"kv/{rid}", c)
+            versions[rid] = store.tiers.versions[f"kv/{rid}"]
+        meta = serve_meta(serve_table(versions, tick))
+        with store.ctx.commit(tick, meta=meta):
+            pass
     store.close()
 
     docs_l = manifest_docs(str(tmp_path / "legacy"))
     docs_c = manifest_docs(str(tmp_path / "ctx"))
     assert docs_l == docs_c and len(docs_l) == 2
 
-    # recovered state identical through the store's recovery path
-    rec = SessionStore(DSMPool(str(tmp_path / "ctx"))).recover(
-        {"k": np.zeros(8, np.float32), "v": np.zeros(4, np.float32)})
-    assert rec is not None and rec.step == 7
-    assert tree_equal(rec.caches["s1"], serve_caches(7)["s1"])
+    # recovered state identical through the context's recovery path
+    objs, step, _ = RecoveryManager(DSMPool(str(tmp_path / "ctx"))).recover(
+        SERVE_TEMPLATES)
+    assert step == 7
+    assert tree_equal(objs["kv/s1"], serve_caches(7)["s1"])
 
 
 def test_serve_crash_cell(tmp_path):
     """Crash inside the session-commit window (pre_flush): no completeOp,
     a restarted store recovers the PREVIOUS committed tick."""
-    from repro.serve.sessions import SessionStore, kv_name
+    from repro.serve.sessions import SessionStore
 
     def hook(point, step):
         if point == "pre_flush" and step >= 7:
@@ -325,22 +334,23 @@ def test_serve_crash_cell(tmp_path):
         caches = serve_caches(tick)
         versions = {}
         for rid, c in caches.items():
-            store.tiers.lstore(kv_name(rid), c)
-            versions[rid] = store.tiers.versions[kv_name(rid)]
-        table = serve_table(versions, tick)
+            store.tiers.lstore(f"kv/{rid}", c)
+            versions[rid] = store.tiers.versions[f"kv/{rid}"]
+        meta = serve_meta(serve_table(versions, tick))
         if tick == 3:
-            store.commit(table, tick)
+            with store.ctx.commit(tick, meta=meta):
+                pass
             committed = caches
         else:
             with pytest.raises(CrashError):
-                store.commit(table, tick)
+                with store.ctx.commit(tick, meta=meta):
+                    pass
     store.ctx.crash()
 
-    restarted = SessionStore(DSMPool(str(tmp_path)))
-    rec = restarted.recover({"k": np.zeros(8, np.float32),
-                             "v": np.zeros(4, np.float32)})
-    assert rec is not None and rec.step == 3        # previous commit
-    assert tree_equal(rec.caches["s1"], committed["s1"])
+    objs, step, _ = RecoveryManager(DSMPool(str(tmp_path))).recover(
+        SERVE_TEMPLATES)
+    assert step == 3                                # previous commit
+    assert tree_equal(objs["kv/s1"], committed["s1"])
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,6 @@
-"""Paged KV layout (repro.serve.paging + the paged SessionStore path):
+"""Paged KV layout (repro.serve.paging + the SessionStore commit path):
 allocator single-ownership, block-table round-trips, slice/assemble
-bit-identity, and paged-engine equivalence against the legacy
-whole-lane layout.
+bit-identity, and a durable engine's equivalence to a store-less one.
 
 The allocator/table invariants also run as hypothesis properties in
 tests/test_paging_props.py; the versions here are deterministic seeded
@@ -232,8 +231,8 @@ def test_read_block_matches_host_slice_of_whole_lane(case, request):
         ctx = ctx_for_mesh(make_mesh(mesh, ("data", "model")))
     cfg = get_smoke_config(arch)
     bundle = build(cfg, dec_pos_len=t_max)
-    kv = TieredKVCache(bundle, 4, t_max, parallel=ctx, block_tokens=bt)
     pager = BlockPager(bundle, t_max, block_tokens=bt)
+    kv = TieredKVCache(bundle, 4, pager, parallel=ctx)
     _random_lanes(kv)
     if plen is not None:
         toks = jax.random.randint(jax.random.PRNGKey(5), (1, plen), 0,
@@ -257,7 +256,7 @@ def test_read_block_matches_host_slice_of_whole_lane(case, request):
         if b != STATE_BLOCK:
             short = min(bt, t_max - b * bt)
             assert all(p.shape[ax] == short for p, ax in zip(
-                parts, (pager._axes[i] for i in pager.tok_idx)))
+                parts, (pager.token_axes[i] for i in pager.tok_idx)))
             parts = pager.pad_block(parts)
             assert [(p.shape, p.dtype) for p in parts] \
                 == [(t.shape, t.dtype) for t in pager.block_template]
@@ -346,17 +345,19 @@ def _build(smoke, tmp, **kw):
                        store=store, commit_every=2, **kw)
 
 
-def test_paged_engine_equivalent_to_legacy(smoke, tmp_path):
-    _, _, _, trace, _ = smoke
-    legacy = _build(smoke, tmp_path / "legacy", paged=False)
-    r0 = legacy.run(trace)
-    legacy.close()
-    paged = _build(smoke, tmp_path / "paged", paged=True, block_tokens=8)
-    r1 = paged.run(trace)
-    paged.close()
+def test_durable_engine_equivalent_to_poolless(smoke, tmp_path):
+    """Committing sessions changes nothing a user sees: a durable engine
+    and a store-less one emit the same tokens in the same ticks with the
+    same prefills."""
+    from repro.serve.engine import ServeEngine
+    _, bundle, params, trace, t_max = smoke
+    r0 = ServeEngine(bundle, params, n_slots=2, t_max=t_max).run(trace)
+    durable = _build(smoke, tmp_path, block_tokens=8)
+    r1 = durable.run(trace)
+    durable.close()
+    assert r1.commits > 0 and r0.commits == 0
     assert r1.outputs == r0.outputs
-    assert (r1.decode_ticks, r1.prefills, r1.commits) \
-        == (r0.decode_ticks, r0.prefills, r0.commits)
+    assert (r1.decode_ticks, r1.prefills) == (r0.decode_ticks, r0.prefills)
 
 
 def test_paged_commit_is_o_blocks_touched(smoke, tmp_path):
@@ -364,7 +365,7 @@ def test_paged_commit_is_o_blocks_touched(smoke, tmp_path):
     the dirty tail blocks, while every clean block is carried by
     reference — the newest manifest still describes the full cache."""
     _, _, _, trace, _ = smoke
-    eng = _build(smoke, tmp_path, paged=True, block_tokens=4)
+    eng = _build(smoke, tmp_path, block_tokens=4)
     eng.submit(trace)
     for _ in range(10):
         eng.tick()
@@ -392,7 +393,7 @@ def test_paged_commit_copies_only_dirty_blocks(smoke, tmp_path):
     token blocks (+ the recurrent state) — never a whole lane — and the
     blocks a commit reads are exactly the token blocks it stages."""
     _, _, _, trace, t_max = smoke
-    eng = _build(smoke, tmp_path / "pool", paged=True, block_tokens=4)
+    eng = _build(smoke, tmp_path / "pool", block_tokens=4)
     pager = eng.pager
     block_bytes = sum(int(np.prod(t.shape)) * t.dtype.itemsize
                       for t in pager.block_template)
@@ -440,16 +441,16 @@ def test_paged_commit_copies_only_dirty_blocks(smoke, tmp_path):
 
 def test_paged_resume_bit_identical(smoke, tmp_path):
     _, _, _, trace, _ = smoke
-    ref = _build(smoke, tmp_path / "ref", paged=True)
+    ref = _build(smoke, tmp_path / "ref")
     r0 = ref.run(trace)
     ref.close()
-    half = _build(smoke, tmp_path / "kill", paged=True)
+    half = _build(smoke, tmp_path / "kill")
     half.submit(trace)
     for _ in range(7):
         half.tick()
     half.store.drain()
     half.close()
-    back = _build(smoke, tmp_path / "kill", paged=True)
+    back = _build(smoke, tmp_path / "kill")
     step = back.resume()
     assert step is not None
     res = back.run(trace)
@@ -463,7 +464,7 @@ def test_paged_recover_falls_back_on_torn_block(smoke, tmp_path):
     sends recovery to the previous manifest — a session table never
     pairs with torn bytes."""
     _, _, _, trace, _ = smoke
-    eng = _build(smoke, tmp_path, paged=True)
+    eng = _build(smoke, tmp_path)
     eng.submit(trace)
     for _ in range(9):
         eng.tick()
@@ -492,7 +493,7 @@ def test_paged_recover_falls_back_on_torn_block(smoke, tmp_path):
     path = pool.payload_path(*victim)
     data = open(path, "rb").read()
     open(path, "wb").write(data[:max(1, len(data) // 2)])
-    back = _build(smoke, tmp_path, paged=True)
+    back = _build(smoke, tmp_path)
     step = back.resume()
     assert step == prev["step"]
     back.close()
@@ -502,14 +503,14 @@ def test_prefix_reuse_skips_prefill_bit_identically(smoke, tmp_path):
     _, _, _, trace, _ = smoke
     shared = [Request(rid=f"a{i}", prompt=trace[0].prompt,
                       max_new_tokens=6) for i in range(3)]
-    e1 = _build(smoke, tmp_path, paged=True, engine_id=1,
+    e1 = _build(smoke, tmp_path, engine_id=1,
                 prefix_reuse=True, prefix_key="t")
     r1 = e1.run(shared)
     e1.close()
     assert r1.prefills >= 1
     again = [Request(rid=f"b{i}", prompt=trace[0].prompt,
                      max_new_tokens=6) for i in range(3)]
-    e2 = _build(smoke, tmp_path, paged=True, engine_id=2,
+    e2 = _build(smoke, tmp_path, engine_id=2,
                 prefix_reuse=True, prefix_key="t")
     r2 = e2.run(again)
     e2.close()

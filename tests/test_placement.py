@@ -1,8 +1,8 @@
 """Cost-driven placement (repro.dsm.placement): decisions must FLIP when
 the emulated topology changes, be logged with their priced alternatives,
 never lose to a fixed strategy, and actually steer the wired layers
-(DurableCommitter shard count/schedule, TieredKVCache.spill_auto,
-cluster rank staging)."""
+(DurableCommitter shard count/schedule, the spill route of a smoke KV
+cache through the tiers, cluster rank staging)."""
 import numpy as np
 import pytest
 
@@ -190,7 +190,7 @@ def test_plan_rank_staging_flips_with_topology():
 
 
 # ---------------------------------------------------------------------------
-# wiring: kv-cache spill_auto (real bundle, both routes restorable)
+# wiring: a KV cache spilled by policy (real bundle, both routes restorable)
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -222,18 +222,20 @@ def _tree_eq(a, b):
 
 
 def test_spill_auto_routes_by_policy_and_restores(smoke_bundle, tmp_path):
-    from repro.serve.kvcache import TieredKVCache
+    from repro.dsm.emu import tree_nbytes
+    from repro.dsm.pool import manifest_entry
     bundle, params = smoke_bundle
     c1 = _filled_cache1(bundle, params)
+    nbytes = tree_nbytes(c1)
 
     # direct-attach: small caches go to staging (host tier + peer buffer)
     tiers = TierManager(DSMPool(str(tmp_path / "a")), 0)
     peer = TierManager(DSMPool(str(tmp_path / "peer")), 1)
-    kv = TieredKVCache(bundle, 2, 32, tiers=tiers,
-                       placement=PlacementPolicy("cxl11-direct"))
-    info = kv.spill_auto("kv/s0", c1, peer=peer)
-    assert info["tier"] == "staging"
-    _tree_eq(kv.restore("kv/s0"), c1)
+    assert PlacementPolicy("cxl11-direct").choose_spill("kv/s0",
+                                                        nbytes) == "staging"
+    tiers.lstore("kv/s0", c1)
+    tiers.rstore("kv/s0", peer, tag=tiers.versions["kv/s0"])
+    _tree_eq(tiers.hbm["kv/s0"], c1)
     # ...and the copy really reached the peer's buffer (survives our loss)
     assert "kv/s0" in peer.staging
 
@@ -243,11 +245,13 @@ def test_spill_auto_routes_by_policy_and_restores(smoke_bundle, tmp_path):
     tiers2 = TierManager(DSMPool(str(tmp_path / "b")), 0)
     pol = PlacementPolicy("cxl30-fabric", p_peer_loss=1.0,
                           replay_ns_per_byte=1e3)
-    kv2 = TieredKVCache(bundle, 2, 32, tiers=tiers2, placement=pol)
-    info2 = kv2.spill_auto("kv/s0", c1)
-    assert info2["tier"] == "pool" and "entry" in info2
+    assert pol.choose_spill("kv/s0", nbytes) == "pool"
+    tiers2.lstore("kv/s0", c1)
+    entry = manifest_entry(tiers2.rflush_sharded(
+        "kv/s0", pol.choose_shards(nbytes, "kv/s0")))
     tiers2.ldiscard("kv/s0")             # force the pool read path
-    _tree_eq(kv2.restore("kv/s0", entry=info2["entry"]), c1)
+    _tree_eq(tiers2.pool.read_entry("kv/s0", entry,
+                                    bundle.abstract_caches(1, 32)), c1)
     decisions = pol.decisions_for("spill")
     assert decisions and decisions[0].choice == "pool"
     tiers.close()

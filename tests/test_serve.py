@@ -1,14 +1,16 @@
-"""The serving subsystem (repro.serve): scheduler contract, tiered KV
-spill/restore, durable sessions, and end-to-end engine properties.
+"""The serving subsystem (repro.serve): scheduler contract, KV-cache
+lanes and the tiers a cold cache moves through, durable sessions, and
+end-to-end engine properties.
 
 Layer by layer:
 
 * scheduler  — pure state machine: admission never exceeds the slot
   count, finished sequences free their slot within one step, FIFO
   fairness under oversubscription;
-* kvcache    — slot surgery is exact; spill/restore round-trips
+* kvcache    — slot surgery is exact; a smoke cache round-trips
   BIT-identically through the host, peer-staging and pool tiers;
-* sessions   — the FliT session commit pairs table + caches atomically;
+* sessions   — the FliT session commit pairs table + block objects
+  atomically, and recovery falls back past a torn block;
   async schedules pair the manifest with the meta captured at flush
   LAUNCH (regression: a later table must never describe older caches);
 * engine     — continuous batching emits tokens identical to the static
@@ -20,11 +22,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.dsm.pool import DSMPool
+from repro.dsm.pool import DSMPool, manifest_entry, partition_leaves
 from repro.dsm.tiers import TierManager
 from repro.serve.kvcache import TieredKVCache
+from repro.serve.paging import STATE_BLOCK, BlockPager, BlockRef, BlockTable
 from repro.serve.scheduler import Request, SlotScheduler
-from repro.serve.sessions import SessionStore, kv_name
+from repro.serve.sessions import Session, SessionStore
 from repro.serve.trace import synthetic_trace, trace_t_max
 
 
@@ -135,7 +138,7 @@ def _filled_cache1(smoke, seed=1):
 
 def test_kv_slot_write_read_roundtrip(smoke, tmp_path):
     _, bundle, _, _, t_max = smoke
-    kv = TieredKVCache(bundle, 4, t_max)
+    kv = TieredKVCache(bundle, 4, BlockPager(bundle, t_max))
     c1 = _filled_cache1(smoke)
     kv.write_slot(2, c1)
     _tree_eq(kv.read_slot(2), c1)
@@ -146,26 +149,23 @@ def test_kv_slot_write_read_roundtrip(smoke, tmp_path):
 
 
 def test_kv_spill_restore_bit_identical_host_tier(smoke, tmp_path):
-    _, bundle, _, _, t_max = smoke
+    """LStore into the host object tier and read it back unchanged."""
     tiers = TierManager(DSMPool(str(tmp_path / "pool")), worker_id=0)
-    kv = TieredKVCache(bundle, 4, t_max, tiers=tiers)
     c1 = _filled_cache1(smoke)
-    kv.spill("kv/s0", c1)
-    _tree_eq(kv.restore("kv/s0"), c1)
+    tiers.lstore("kv/s0", c1)
+    _tree_eq(tiers.hbm["kv/s0"], c1)
 
 
 def test_kv_spill_restore_bit_identical_peer_staging(smoke, tmp_path):
-    """RStore into a peer's host buffer, then OUR crash: the peer-side
-    manager restores the exact bytes from its staging tier."""
-    _, bundle, _, _, t_max = smoke
+    """RStore into a peer's host buffer, then OUR crash: the peer reads
+    the exact bytes back from its staging tier."""
     ours = TierManager(DSMPool(str(tmp_path / "a")), worker_id=0)
     peer = TierManager(DSMPool(str(tmp_path / "b")), worker_id=1)
-    kv_ours = TieredKVCache(bundle, 4, t_max, tiers=ours)
-    kv_peer = TieredKVCache(bundle, 4, t_max, tiers=peer)
     c1 = _filled_cache1(smoke)
-    kv_ours.spill("kv/s0", c1, peer=peer)
+    ours.lstore("kv/s0", c1)
+    ours.rstore("kv/s0", peer, tag=ours.versions["kv/s0"])
     ours.crash()                              # our volatile tiers vanish
-    restored = kv_peer.restore("kv/s0")
+    restored = peer.rload("kv/s0")
     assert restored is not None
     _tree_eq(restored, c1)
 
@@ -175,19 +175,22 @@ def test_kv_spill_durable_pool_roundtrip(smoke, tmp_path):
     bit-identical including non-native dtypes (bf16 raw-view storage)."""
     _, bundle, _, _, t_max = smoke
     tiers = TierManager(DSMPool(str(tmp_path / "pool")), worker_id=0)
-    kv = TieredKVCache(bundle, 4, t_max, tiers=tiers)
     c1 = _filled_cache1(smoke)
-    entry = kv.spill_durable("kv/s0", c1, n_blocks=2)
+    assert any(l.dtype == jnp.bfloat16
+               for l in jax.tree_util.tree_leaves(c1))
+    tiers.lstore("kv/s0", c1)
+    entry = manifest_entry(tiers.rflush_sharded("kv/s0", 2))
     tiers.crash()                             # host tier gone: pool only
-    restored = kv.restore("kv/s0", entry)
+    restored = tiers.pool.read_entry("kv/s0", entry,
+                                     bundle.abstract_caches(1, t_max))
     _tree_eq(restored, c1)
 
 
 def test_kv_block_layout_covers_all_leaves_byte_balanced(smoke):
     _, bundle, _, _, t_max = smoke
-    kv = TieredKVCache(bundle, 4, t_max)
-    leaves = jax.tree_util.tree_leaves(kv.template1)
-    layout = kv.block_layout(2)
+    leaves = jax.tree_util.tree_leaves(bundle.abstract_caches(1, t_max))
+    layout = partition_leaves(
+        [int(np.prod(l.shape)) * l.dtype.itemsize for l in leaves], 2)
     flat = sorted(i for g in layout for i in g)
     assert flat == list(range(len(leaves)))   # exact cover, no dupes
     assert all(g for g in layout)             # no empty block
@@ -197,49 +200,65 @@ def test_kv_block_layout_covers_all_leaves_byte_balanced(smoke):
 # durable sessions
 # ---------------------------------------------------------------------------
 
+def _stage_session(store, pager, s, cache1, table):
+    """Stage session ``s``'s dirty blocks of ``cache1`` for the next
+    commit, as the engine does from a lane."""
+    for blk, leaves in pager.slice_dirty(cache1, s.pos, table).items():
+        ref = table.refs.setdefault(blk, BlockRef(
+            blk=blk, bid=len(table.refs), tokens=0,
+            name=store.block_name(s.rid, blk)))
+        if blk != STATE_BLOCK:
+            ref.tokens = pager.tokens_in_block(blk, s.pos)
+        store.stage_block(s, ref, leaves)
+
+
 def test_session_commit_and_recover(smoke, tmp_path):
-    from repro.serve.sessions import Session
     _, bundle, _, _, t_max = smoke
+    pager = BlockPager(bundle, t_max)
     store = SessionStore(DSMPool(str(tmp_path / "pool")))
-    kv = TieredKVCache(bundle, 4, t_max, tiers=store.tiers)
-    c1 = _filled_cache1(smoke)
-    s = Session("r0", (1, 2, 3), 8, emitted=[7, 9])
-    store.stage(s, c1)
-    store.commit({"r0": s}, step=4)
+    c1 = _filled_cache1(smoke)                # 16 prefilled positions
+    s = Session("r0", tuple(range(16)), 8, emitted=[7, 9])
+    table = BlockTable()
+    _stage_session(store, pager, s, c1, table)
+    store.commit_paged({"r0": s}, {"r0": table}, step=4,
+                       block_tokens=pager.block_tokens)
     store.close()
 
     store2 = SessionStore(DSMPool(str(tmp_path / "pool")))
-    rec = store2.recover(kv.template1)
+    rec = store2.recover(pager=pager)
     assert rec is not None and rec.step == 4
     assert rec.sessions["r0"].emitted == [7, 9]
-    assert rec.sessions["r0"].pos == 3 + 2 - 1
+    assert rec.sessions["r0"].pos == 16 + 2 - 1
     _tree_eq(rec.caches["r0"], c1)
 
 
 def test_session_recover_falls_back_on_torn_commit(smoke, tmp_path):
-    """Corrupting the newest commit's cache file must push recovery to the
-    previous manifest — a session table can never pair with torn bytes."""
+    """Corrupting the newest commit's block object must push recovery to
+    the previous manifest — a session table can never pair with torn
+    bytes."""
     import os
-    from repro.serve.sessions import Session
     _, bundle, _, _, t_max = smoke
-    pool = DSMPool(str(tmp_path / "pool"))
-    store = SessionStore(pool)
-    kv = TieredKVCache(bundle, 4, t_max, tiers=store.tiers)
+    pager = BlockPager(bundle, t_max)
+    store = SessionStore(DSMPool(str(tmp_path / "pool")))
     s = Session("r0", (1, 2, 3), 8, emitted=[7])
-    store.stage(s, _filled_cache1(smoke, seed=1))
-    store.commit({"r0": s}, step=2)
-    s.emitted.append(8)
-    store.stage(s, _filled_cache1(smoke, seed=2))
-    store.commit({"r0": s}, step=4)
+    table = BlockTable()
+    _stage_session(store, pager, s, _filled_cache1(smoke, seed=1), table)
+    store.commit_paged({"r0": s}, {"r0": table}, step=2,
+                       block_tokens=pager.block_tokens)
+    s.emitted.append(8)                       # block 0 grows: dirty again
+    _stage_session(store, pager, s, _filled_cache1(smoke, seed=2), table)
+    store.commit_paged({"r0": s}, {"r0": table}, step=4,
+                       block_tokens=pager.block_tokens)
     store.close()
-    # tear the newest commit: clobber its cache object payload
-    obj_dir = os.path.join(str(tmp_path / "pool"), "objects", kv_name("r0"))
+    # tear the newest commit: clobber its block object payload
+    obj_dir = os.path.join(str(tmp_path / "pool"), "objects",
+                           store.block_name("r0", 0))
     newest = sorted(f for f in os.listdir(obj_dir)
                     if f.endswith((".npz", ".cxl0")))[-1]
     with open(os.path.join(obj_dir, newest), "wb") as f:
         f.write(b"torn")
     rec = SessionStore(DSMPool(str(tmp_path / "pool"))).recover(
-        kv.template1)
+        pager=pager)
     assert rec is not None and rec.step == 2
     assert rec.sessions["r0"].emitted == [7]
 
